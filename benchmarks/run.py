@@ -17,6 +17,7 @@ import os
 import sys
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.obs import export, trace as obs_trace
 from repro.obs.metrics import registry
 
@@ -53,6 +54,7 @@ def main() -> None:
                     help="arm deterministic fault injection at the default "
                          "sites (repro.resilience.chaos) for the whole run")
     args = ap.parse_args()
+    use_compile_cache()
     if args.chaos:
         from repro.resilience import chaos
         chaos.configure_spec(args.chaos)

@@ -5,24 +5,32 @@ phase-3 segment reduce, and the per-vertex apply — with a
 ``(num_blocks, local_budget, d)`` partial slab round-tripping through HBM
 between them.  These kernels fuse all three:
 
-* **pull** — grid ``(num_tiles, num_blocks)``: the *output tile* BlockSpec
-  ignores the inner (block) dimension, so the tile stays VMEM-resident
-  while every cache block streams its gather/edge/mask windows through
-  double-buffered DMA (Pallas pipelines the next block's windows while the
-  current one computes).  Each block accumulates into a local
-  ``(local_budget, d)`` register/VMEM buffer and folds it straight into the
-  resident tile via ``id_map`` — the partial slab never exists.  On the last
-  block the epilogue (``out·mul + add``: PageRank damping / SpMV scale)
-  is applied in place, so the apply kernel disappears too.
-* **push** — grid ``(num_blocks,)``: row blocking gives each block a
-  *disjoint* destination window (= the output tile), and the whole source
-  vector rides a constant BlockSpec so it is fetched once and stays
-  resident; the ``block_contrib`` gather happens in VMEM instead of
-  materializing an HBM slab.
+* **pull** — grid ``(num_tiles, num_blocks, chunks)``: the output tile is a
+  VMEM scratch that stays resident across every cache block and is written
+  to HBM once, after the epilogue.  Each block's source-value window rides
+  a BlockSpec that ignores the chunk axis, so it is fetched once per block
+  while the block's edge chunks stream through SMEM.  Each block
+  accumulates into a local ``(local_budget, d)`` VMEM buffer and folds it
+  into the tile via ``id_map`` — the partial slab never exists.  On the
+  last block the epilogue (``out·mul + add``: PageRank damping / SpMV
+  scale) is applied in place, so the apply kernel disappears too.
+* **push** — grid ``(num_blocks, chunks)``: row blocking gives each block a
+  *disjoint* destination window (= the output block), and the whole source
+  vector is copied into VMEM once; each edge reads its source row there
+  through ``id_map`` instead of from an HBM ``block_contrib`` slab.
 
-Accumulation order matches the slab engines' scatter order exactly (chunked
-``.at[].add`` in edge-slot order within a block, block-major across
-blocks), so results are bit-identical — asserted in tests/test_fused.py.
+Every per-edge index lives in SMEM.  A chunk is processed in two passes:
+gather each edge's message (one scalar-addressed row read in VMEM) into a
+``(chunk, d)`` buffer, then fold the messages into the accumulator (one
+row read-modify-write each) — see :mod:`repro.kernels.common` for why.
+Keeping the messages a separate pass also keeps the weight multiply and
+the accumulate two roundings, as in the slab engines (a compiler may not
+contract them into one FMA).  Only the block's real edges are visited
+(``n_edges``), in slot order, so accumulation order matches the slab
+engines' scatter order: block-major, edge-slot order within a block.  In
+interpret mode that makes the results bit-identical (asserted in
+tests/test_fused.py).  ``order`` (scalar-prefetched) is the block visit
+order; it only moves index maps, no slab is permuted.
 """
 from __future__ import annotations
 
@@ -32,100 +40,103 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.partition import REDUCE_IDENTITY
+from repro.kernels.common import edge_chunks, lane_chunk, vmem_limit
 
-__all__ = ["fused_pull_pallas", "fused_push_pallas", "LANE"]
+__all__ = ["fused_pull_pallas", "fused_push_pallas"]
 
-LANE = 128  # TPU lane width; feature dims are padded to multiples of this
-
-
-def _pick_chunk(edge_budget: int, chunk: int) -> int:
-    """Largest divisor of ``edge_budget`` ≤ ``chunk`` (edge budgets are
-    128-padded, so this never degrades below 128 for the default 512)."""
-    chunk = max(1, min(chunk, edge_budget))
-    while edge_budget % chunk:
-        chunk -= 1
-    return chunk
+_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 
-def _chunk_messages(window, widx_ref, cidx_ref, ev_ref, mask_ref, sl,
-                    reduce: str, combine, weighted: bool):
-    """Gather + weight + mask one edge chunk from the VMEM-resident refs."""
-    widx = widx_ref[0, sl]
-    cidx = cidx_ref[0, sl]
-    msgs = jnp.take(window, widx, axis=0)  # confined random read (VMEM)
-    if weighted:
-        ev = ev_ref[0, sl][:, None]
-        msgs = combine(msgs, ev) if combine is not None else msgs * ev
-    mask = mask_ref[0, sl] > 0
-    ident = jnp.asarray(REDUCE_IDENTITY[reduce], msgs.dtype)
-    return jnp.where(mask[:, None], msgs, ident), cidx, mask
+def _chunk_edges(n_edges, c, chunk: int):
+    """Real edges of a block that fall in its chunk ``c``."""
+    return jnp.clip(n_edges - c * chunk, 0, chunk)
+
+
+def _message(row, ev_ref, e, combine, weighted: bool):
+    """One edge's message from its gathered ``(1, d)`` value row."""
+    ev = ev_ref[0, e] if weighted else None
+    if combine is not None:
+        return combine(row, ev)
+    return row * ev if weighted else row
+
+
+def _edge_specs(chunk: int, weighted: bool, index_map):
+    n = 3 if weighted else 2
+    return [pl.BlockSpec((None, None, 1, chunk), index_map,
+                         memory_space=pltpu.SMEM)] * n
 
 
 def _fused_pull_kernel(
-    win_ref,    # (block_size, d)       the block's source-value window
-    widx_ref,   # (1, edge_budget)      src index within the window
-    cidx_ref,   # (1, edge_budget)      compacted dst local id (pad → 0)
-    ev_ref,     # (1, edge_budget)      edge values (ignored if unweighted)
-    mask_ref,   # (1, edge_budget)      1.0 on real edges, 0.0 on padding
-    idmap_ref,  # (1, local_budget)     local dst → global dst (pad → n)
-    eps_ref,    # (1, 2)                epilogue (mul, add)
-    out_ref,    # (tile_rows, d)        VMEM-resident output tile
-    *,
-    chunk: int,
+    order_ref,  # (num_blocks,)  SMEM   block visit order
+    ne_ref,     # (num_blocks,)  SMEM   real edges per block
+    nl_ref,     # (num_blocks,)  SMEM   compacted rows per block
+    win_ref,    # (block_size, d)       VMEM  the block's source-value window
+    widx_ref,   # (1, chunk)            SMEM  src index within the window
+    cidx_ref,   # (1, chunk)            SMEM  compacted dst local id
+    *rest,      # [ev (1, chunk) SMEM], idmap, eps, out, msgs, acc, tile, sem
     reduce: str,
     combine: Optional[Callable],
     weighted: bool,
     fuse_epilogue: bool,
 ):
-    t = pl.program_id(0)
-    b = pl.program_id(1)
-    nb = pl.num_programs(1)
-    local_budget = idmap_ref.shape[1]
-    d = out_ref.shape[1]
-    tile_rows = out_ref.shape[0]
-    edge_budget = widx_ref.shape[1]
+    ev_ref = rest[0] if weighted else None
+    idmap_ref, eps_ref, out_hbm, msgs_ref, acc_ref, tile_ref, sem = rest[-7:]
+    t, b, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    nb, nc = pl.num_programs(1), pl.num_programs(2)
+    blk = order_ref[b]
+    chunk = widx_ref.shape[1]
+    tile_rows = tile_ref.shape[0]
     ident = REDUCE_IDENTITY[reduce]
+    op = _OPS[reduce]
 
-    @pl.when(b == 0)
+    @pl.when((b == 0) & (c == 0))
     def _init_tile():
-        out_ref[...] = jnp.full((tile_rows, d), ident, out_ref.dtype)
+        tile_ref[...] = jnp.full(tile_ref.shape, ident, jnp.float32)
 
-    def body(c, acc):
-        sl = pl.dslice(c * chunk, chunk)
-        msgs, cidx, _ = _chunk_messages(
-            win_ref[...], widx_ref, cidx_ref, ev_ref, mask_ref, sl,
-            reduce, combine, weighted)
-        # padded slots carry the identity and (stored) cidx 0 — the exact
-        # operand stream of the slab path's flat segment reduce
-        if reduce == "sum":
-            return acc.at[cidx].add(msgs)
-        if reduce == "min":
-            return acc.at[cidx].min(msgs)
-        return acc.at[cidx].max(msgs)
+    @pl.when(c == 0)
+    def _init_acc():
+        acc_ref[...] = jnp.full(acc_ref.shape, ident, jnp.float32)
 
-    acc = jnp.full((local_budget, d), ident, jnp.float32)
-    acc = jax.lax.fori_loop(0, edge_budget // chunk, body, acc, unroll=False)
+    def gather(e, carry):
+        msgs_ref[pl.ds(e, 1), :] = _message(
+            win_ref[pl.ds(widx_ref[0, e], 1), :], ev_ref, e, combine,
+            weighted)
+        return carry
 
-    # Fold the block's compacted partial straight into the resident tile.
-    gid = idmap_ref[0, :]
-    loc = gid - t * tile_rows
-    oob = (loc < 0) | (loc >= tile_rows)
-    loc = jnp.where(oob, tile_rows, loc)  # out-of-tile → dropped
-    tile = out_ref[...]
-    if reduce == "sum":
-        tile = tile.at[loc].add(acc, mode="drop")
-    elif reduce == "min":
-        tile = tile.at[loc].min(acc, mode="drop")
-    else:
-        tile = tile.at[loc].max(acc, mode="drop")
-    out_ref[...] = tile
+    def scatter(e, carry):
+        row = pl.ds(cidx_ref[0, e], 1)
+        acc_ref[row, :] = op(acc_ref[row, :], msgs_ref[pl.ds(e, 1), :])
+        return carry
 
-    if fuse_epilogue:
-        @pl.when(b == nb - 1)
-        def _epilogue():
-            out_ref[...] = out_ref[...] * eps_ref[0, 0] + eps_ref[0, 1]
+    live = _chunk_edges(ne_ref[blk], c, chunk)
+    jax.lax.fori_loop(0, live, gather, 0)
+    jax.lax.fori_loop(0, live, scatter, 0)
+
+    @pl.when(c == nc - 1)
+    def _fold():
+        # fold the block's compacted partial straight into the resident tile
+        def fold(lid, carry):
+            loc = idmap_ref[0, lid] - t * tile_rows
+
+            @pl.when((loc >= 0) & (loc < tile_rows))
+            def _():
+                tile_ref[pl.ds(loc, 1), :] = op(tile_ref[pl.ds(loc, 1), :],
+                                                acc_ref[pl.ds(lid, 1), :])
+            return carry
+
+        jax.lax.fori_loop(0, nl_ref[blk], fold, 0)
+
+    @pl.when((b == nb - 1) & (c == nc - 1))
+    def _flush():
+        if fuse_epilogue:
+            tile_ref[...] = tile_ref[...] * eps_ref[0, 0] + eps_ref[0, 1]
+        copy = pltpu.make_async_copy(
+            tile_ref, out_hbm.at[pl.ds(t * tile_rows, tile_rows)], sem.at[0])
+        copy.start()
+        copy.wait()
 
 
 @functools.partial(
@@ -135,19 +146,21 @@ def _fused_pull_kernel(
                      "fuse_epilogue", "interpret"),
 )
 def fused_pull_pallas(
-    values,       # f32[num_blocks*block_size, d]  (padded)
+    values,       # f32[num_blocks*block_size, d]  (padded, d % 128 == 0)
     window_idx,   # i32[num_blocks, edge_budget]
     compact_idx,  # i32[num_blocks, edge_budget]
-    edge_vals,    # f32[num_blocks, edge_budget]
-    edge_mask,    # f32[num_blocks, edge_budget]  (1.0 real / 0.0 pad)
+    edge_vals,    # f32[num_blocks, edge_budget]   (unused if not weighted)
+    n_edges,      # i32[num_blocks]   real edges (slots 0..n_edges-1)
+    n_local,      # i32[num_blocks]   compacted rows
     id_map,       # i32[num_blocks, local_budget]  (pad = n → dropped)
+    order,        # i32[num_blocks]   block visit order
     epilogue,     # f32[1, 2]  (mul, add); identity when fuse_epilogue=False
     *,
     block_size: int,
     local_budget: int,
     tile_rows: int,
     num_tiles: int = 1,
-    chunk: int = 512,
+    chunk: int = 2048,
     reduce: str = "sum",
     combine: Optional[Callable] = None,
     weighted: bool = True,
@@ -156,87 +169,109 @@ def fused_pull_pallas(
 ):
     """Fused pull: returns f32[num_tiles*tile_rows, d] — no partial slab.
 
-    A single tile sized to the padded output covers every graph in the
-    repo's suite; multi-tile runs trade VMEM for replaying each block's
-    edge stream once per tile."""
+    A single tile sized to the padded output covers every graph whose tile
+    fits VMEM; larger graphs raise here, before the kernel is built."""
     num_blocks, edge_budget = window_idx.shape
     d = values.shape[1]
     assert values.shape[0] == num_blocks * block_size, (
         f"values must be padded to num_blocks*block_size, got {values.shape}")
-    chunk = _pick_chunk(edge_budget, chunk)
-    grid = (num_tiles, num_blocks)
+    chunk = lane_chunk(edge_budget, chunk)
+    need = 4 * d * (2 * block_size + chunk + local_budget + tile_rows)
+    # double-buffered index rows + the three scalar-prefetched vectors
+    smem = 8 * (local_budget + 3 * chunk) + 12 * num_blocks
+    limit = vmem_limit(need, f"fused pull (tile of {tile_rows} rows × {d})",
+                       smem)
+    slabs = [edge_chunks(window_idx, chunk), edge_chunks(compact_idx, chunk)]
+    if weighted:
+        slabs.append(edge_chunks(edge_vals, chunk))
+    grid = (num_tiles, num_blocks, slabs[0].shape[1])
     kernel = functools.partial(
-        _fused_pull_kernel, chunk=chunk, reduce=reduce, combine=combine,
+        _fused_pull_kernel, reduce=reduce, combine=combine,
         weighted=weighted, fuse_epilogue=fuse_epilogue)
-    return pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_size, d), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, local_budget), lambda t, b: (b, 0)),
-            pl.BlockSpec((1, 2), lambda t, b: (0, 0)),
+            pl.BlockSpec((block_size, d),
+                         lambda t, b, c, o, ne, nl: (o[b], 0)),
+            *_edge_specs(chunk, weighted,
+                         lambda t, b, c, o, ne, nl: (o[b], c, 0, 0)),
+            pl.BlockSpec((None, 1, local_budget),
+                         lambda t, b, c, o, ne, nl: (o[b], 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        # index map ignores b → the tile stays resident across the inner
-        # (cache block) grid dimension and is flushed once per tile
-        out_specs=pl.BlockSpec((tile_rows, d), lambda t, b: (t, 0)),
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((chunk, d), jnp.float32),
+            pltpu.VMEM((local_budget, d), jnp.float32),
+            pltpu.VMEM((tile_rows, d), jnp.float32),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_tiles * tile_rows, d),
                                        jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 3, vmem_limit_bytes=limit),
         interpret=interpret,
-    )(values, window_idx, compact_idx, edge_vals, edge_mask, id_map,
-      epilogue)
+        name="tocab_fused_pull",
+    )(order, n_edges, n_local, values, *slabs,
+      id_map[:, None, :], epilogue)
 
 
 def _fused_push_kernel(
-    values_ref,  # (n_pad, d)            whole source vector, VMEM-resident
-    widx_ref,    # (1, edge_budget)      dst index within the block window
-    cidx_ref,    # (1, edge_budget)      compacted src local id
-    ev_ref,      # (1, edge_budget)
-    mask_ref,    # (1, edge_budget)
-    idmap_ref,   # (1, local_budget)     local src → global src (pad = n)
-    eps_ref,     # (1, 2)
-    out_ref,     # (block_size, d)       the block's disjoint dst window
-    *,
-    chunk: int,
+    order_ref,  # (num_blocks,)  SMEM   block visit order
+    ne_ref,     # (num_blocks,)  SMEM   real edges per block
+    vals_hbm,   # (n_pad, d)     HBM    whole source vector
+    widx_ref,   # (1, chunk)     SMEM   dst index within the block window
+    cidx_ref,   # (1, chunk)     SMEM   compacted src local id
+    *rest,      # [ev (1, chunk) SMEM], idmap, eps, out, msgs, vals, sem
     reduce: str,
     combine: Optional[Callable],
     weighted: bool,
     fuse_epilogue: bool,
 ):
-    block_size = out_ref.shape[0]
-    edge_budget = widx_ref.shape[1]
-    ident = REDUCE_IDENTITY[reduce]
+    ev_ref = rest[0] if weighted else None
+    idmap_ref, eps_ref, out_ref, msgs_ref, vals_ref, sem = rest[-6:]
+    b, c = pl.program_id(0), pl.program_id(1)
+    nc = pl.num_programs(1)
+    chunk = widx_ref.shape[1]
+    op = _OPS[reduce]
 
-    # in-VMEM block_contrib: each distinct source fetched once per block
-    contrib = jnp.take(values_ref[...], idmap_ref[0, :], axis=0)
+    @pl.when((b == 0) & (c == 0))
+    def _load_values():
+        copy = pltpu.make_async_copy(vals_hbm, vals_ref, sem.at[0])
+        copy.start()
+        copy.wait()
 
-    def body(c, acc):
-        sl = pl.dslice(c * chunk, chunk)
-        cidx = cidx_ref[0, sl]
-        msgs = jnp.take(contrib, cidx, axis=0)
-        if weighted:
-            ev = ev_ref[0, sl][:, None]
-            msgs = combine(msgs, ev) if combine is not None else msgs * ev
-        mask = mask_ref[0, sl] > 0
-        msgs = jnp.where(mask[:, None], msgs,
-                         jnp.asarray(ident, msgs.dtype))
-        # padded edges → scratch row block_size (slab: segment n → dropped)
-        wid = jnp.where(mask, widx_ref[0, sl], block_size)
-        if reduce == "sum":
-            return acc.at[wid].add(msgs, mode="drop")
-        if reduce == "min":
-            return acc.at[wid].min(msgs, mode="drop")
-        return acc.at[wid].max(msgs, mode="drop")
+    @pl.when(c == 0)
+    def _init_window():
+        out_ref[...] = jnp.full(out_ref.shape, REDUCE_IDENTITY[reduce],
+                                jnp.float32)
 
-    d = out_ref.shape[1]
-    acc = jnp.full((block_size, d), ident, jnp.float32)
-    acc = jax.lax.fori_loop(0, edge_budget // chunk, body, acc, unroll=False)
+    def gather(e, carry):
+        # block_contrib gather, in VMEM: local src → global src → value row
+        src = idmap_ref[0, cidx_ref[0, e]]
+        msgs_ref[pl.ds(e, 1), :] = _message(
+            vals_ref[pl.ds(src, 1), :], ev_ref, e, combine, weighted)
+        return carry
+
+    def scatter(e, carry):
+        row = pl.ds(widx_ref[0, e], 1)
+        out_ref[row, :] = op(out_ref[row, :], msgs_ref[pl.ds(e, 1), :])
+        return carry
+
+    live = _chunk_edges(ne_ref[order_ref[b]], c, chunk)
+    jax.lax.fori_loop(0, live, gather, 0)
+    jax.lax.fori_loop(0, live, scatter, 0)
+
     if fuse_epilogue:
-        acc = acc * eps_ref[0, 0] + eps_ref[0, 1]
-    out_ref[...] = acc.astype(out_ref.dtype)
+        @pl.when(c == nc - 1)
+        def _epilogue():
+            out_ref[...] = out_ref[...] * eps_ref[0, 0] + eps_ref[0, 1]
 
 
 @functools.partial(
@@ -245,49 +280,68 @@ def _fused_push_kernel(
                      "combine", "weighted", "fuse_epilogue", "interpret"),
 )
 def fused_push_pallas(
-    values,       # f32[n_pad, d]  (n_pad ≥ n+1 so padded id_map reads 0)
+    values,       # f32[n_pad, d]  (n_pad % 8 == 0, d % 128 == 0)
     window_idx,   # i32[num_blocks, edge_budget]
     compact_idx,  # i32[num_blocks, edge_budget]
-    edge_vals,    # f32[num_blocks, edge_budget]
-    edge_mask,    # f32[num_blocks, edge_budget]
+    edge_vals,    # f32[num_blocks, edge_budget]   (unused if not weighted)
+    n_edges,      # i32[num_blocks]
     id_map,       # i32[num_blocks, local_budget]
+    order,        # i32[num_blocks]   block visit order
     epilogue,     # f32[1, 2]
     *,
     block_size: int,
     local_budget: int,
-    chunk: int = 512,
+    chunk: int = 2048,
     reduce: str = "sum",
     combine: Optional[Callable] = None,
     weighted: bool = True,
     fuse_epilogue: bool = False,
     interpret: bool = True,
 ):
-    """Fused push: returns f32[num_blocks*block_size, d] (slice to n).
+    """Fused push: returns f32[num_blocks*block_size, d] (slice to n), in
+    natural block order whatever ``order`` visits.
 
-    The ``block_contrib`` slab of the slab engine is replaced by an
-    in-kernel gather from the resident ``values``."""
+    The ``block_contrib`` slab of the slab engine is replaced by reads of
+    the VMEM-resident ``values``."""
     num_blocks, edge_budget = window_idx.shape
     n_pad, d = values.shape
-    chunk = _pick_chunk(edge_budget, chunk)
+    chunk = lane_chunk(edge_budget, chunk)
+    need = 4 * d * (n_pad + 2 * block_size + chunk)
+    smem = 8 * (local_budget + 3 * chunk) + 8 * num_blocks
+    limit = vmem_limit(need, f"fused push (source vector of {n_pad} rows "
+                             f"× {d})", smem)
+    slabs = [edge_chunks(window_idx, chunk), edge_chunks(compact_idx, chunk)]
+    if weighted:
+        slabs.append(edge_chunks(edge_vals, chunk))
     kernel = functools.partial(
-        _fused_push_kernel, chunk=chunk, reduce=reduce, combine=combine,
+        _fused_push_kernel, reduce=reduce, combine=combine,
         weighted=weighted, fuse_epilogue=fuse_epilogue)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(num_blocks, slabs[0].shape[1]),
+        in_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),
+            *_edge_specs(chunk, weighted,
+                         lambda b, c, o, ne: (o[b], c, 0, 0)),
+            pl.BlockSpec((None, 1, local_budget),
+                         lambda b, c, o, ne: (o[b], 0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+        ],
+        out_specs=pl.BlockSpec((block_size, d), lambda b, c, o, ne: (o[b], 0)),
+        scratch_shapes=[
+            pltpu.VMEM((chunk, d), jnp.float32),
+            pltpu.VMEM((n_pad, d), jnp.float32),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+    )
     return pl.pallas_call(
         kernel,
-        grid=(num_blocks,),
-        in_specs=[
-            # constant index map → fetched once, resident across all blocks
-            pl.BlockSpec((n_pad, d), lambda b: (0, 0)),
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, local_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, 2), lambda b: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_size, d), lambda b: (b, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_blocks * block_size, d),
                                        jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 2, vmem_limit_bytes=limit),
         interpret=interpret,
-    )(values, window_idx, compact_idx, edge_vals, edge_mask, id_map,
-      epilogue)
+        name="tocab_fused_push",
+    )(order, n_edges, values, *slabs, id_map[:, None, :], epilogue)

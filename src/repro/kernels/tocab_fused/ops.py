@@ -24,10 +24,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.partition import BlockedGraph
+from repro.kernels.common import LANE, roundup
 from repro.obs.metrics import registry as _obs
 from repro.resilience import chaos as _chaos
 
-from .kernel import LANE, fused_pull_pallas, fused_push_pallas
+from .kernel import fused_pull_pallas, fused_push_pallas
 from .ref import fused_edge_reduce_ref, fused_pull_ref, fused_push_ref
 
 __all__ = ["fused_pull", "fused_push", "fused_edge_reduce",
@@ -36,10 +37,6 @@ __all__ = ["fused_pull", "fused_push", "fused_edge_reduce",
 
 def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "jax"
-
-
-def _roundup(x: int, to: int) -> int:
-    return -(-x // to) * to
 
 
 def _record_fused(bg: BlockedGraph, engine: str, tail: Tuple[int, ...],
@@ -57,16 +54,23 @@ def _record_fused(bg: BlockedGraph, engine: str, tail: Tuple[int, ...],
 
 
 def _pallas_edges(bg: BlockedGraph, combine):
-    """Edge-value / mask slabs + weighted flag in the kernels' layout."""
+    """Edge-value slab + weighted flag + effective combine for the kernels
+    (which visit only real edge slots, so no mask is needed)."""
     from repro.core.balance import UNWEIGHTED
 
-    mask_f = bg.edge_mask.astype(jnp.float32)
-    ev = bg.edge_vals
     if combine is UNWEIGHTED:
-        combine, ev = None, None
-    if ev is None:
-        return mask_f, mask_f, False, combine  # ev slot unused
-    return jnp.where(bg.edge_mask, ev, 0.0), mask_f, True, combine
+        return None, False, None
+    return bg.edge_vals, bg.edge_vals is not None, combine
+
+
+def _visit_order(bg: BlockedGraph, block_order) -> jnp.ndarray:
+    order = range(bg.num_blocks) if block_order is None else block_order
+    return jnp.asarray([int(b) for b in order], jnp.int32)
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return interpret if interpret is not None else \
+        jax.default_backend() != "tpu"
 
 
 def _epilogue_arr(epilogue) -> Tuple[jnp.ndarray, bool]:
@@ -95,7 +99,7 @@ def fused_pull(
     interpret: Optional[bool] = None,
     block_order: Optional[Sequence[int]] = None,
     tile_rows: Optional[int] = None,
-    chunk: int = 512,
+    chunk: int = 2048,
 ):
     """out[dst] = ⊕ values[src] (⊗ edge_val), partials never leaving fast
     memory; optional affine epilogue ``out*mul + add`` fused in."""
@@ -116,27 +120,19 @@ def fused_pull(
     squeeze = values.ndim == 1
     x = values[:, None] if squeeze else values
     n, d = x.shape
-    d_pad = _roundup(d, LANE)
+    d_pad = roundup(d, LANE)
     rows_pad = bg.num_blocks * bg.block_size
     vals = jnp.zeros((rows_pad, d_pad), jnp.float32)
     vals = vals.at[:n, :d].set(x.astype(jnp.float32))
-    ev, mask_f, weighted, combine = _pallas_edges(bg, combine)
-    widx, cidx, idmap = bg.window_idx, bg.compact_idx, bg.id_map
-    if block_order is not None:
-        idx = jnp.asarray(tuple(block_order), jnp.int32)
-        widx, cidx, ev, mask_f, idmap = (
-            jnp.take(a, idx, axis=0) for a in (widx, cidx, ev, mask_f, idmap))
-        vals = jnp.take(vals.reshape(bg.num_blocks, bg.block_size, d_pad),
-                        idx, axis=0).reshape(rows_pad, d_pad)
+    ev, weighted, combine = _pallas_edges(bg, combine)
     eps, fuse_eps = _epilogue_arr(epilogue)
-    tile_rows = tile_rows or _roundup(bg.n, 8)
     out = fused_pull_pallas(
-        vals, widx, cidx, ev, mask_f, idmap, eps,
+        vals, bg.window_idx, bg.compact_idx, ev, bg.n_edges, bg.n_local,
+        bg.id_map, _visit_order(bg, block_order), eps,
         block_size=bg.block_size, local_budget=bg.local_budget,
-        tile_rows=tile_rows, num_tiles=1, chunk=chunk, reduce=reduce,
-        combine=combine, weighted=weighted, fuse_epilogue=fuse_eps,
-        interpret=interpret if interpret is not None
-        else jax.default_backend() != "tpu")
+        tile_rows=tile_rows or roundup(bg.n, 8), num_tiles=1, chunk=chunk,
+        reduce=reduce, combine=combine, weighted=weighted,
+        fuse_epilogue=fuse_eps, interpret=_interpret(interpret))
     out = out[: bg.n, :d]
     return out[:, 0] if squeeze else out
 
@@ -150,7 +146,7 @@ def fused_push(
     backend: Optional[str] = None,
     interpret: Optional[bool] = None,
     block_order: Optional[Sequence[int]] = None,
-    chunk: int = 512,
+    chunk: int = 2048,
 ):
     """Push with the ``block_contrib`` gather kept in fast memory.  Blocks
     own disjoint destination windows, so any ``block_order`` (the balance
@@ -176,33 +172,17 @@ def fused_push(
     squeeze = values.ndim == 1
     x = values[:, None] if squeeze else values
     n, d = x.shape
-    d_pad = _roundup(d, LANE)
-    n_pad = _roundup(n + 1, 8)  # padded id_map entries (= n) must read 0
-    vals = jnp.zeros((n_pad, d_pad), jnp.float32)
+    d_pad = roundup(d, LANE)
+    vals = jnp.zeros((roundup(n, 8), d_pad), jnp.float32)
     vals = vals.at[:n, :d].set(x.astype(jnp.float32))
-    ev, mask_f, weighted, combine = _pallas_edges(bg, combine)
-    widx, cidx, idmap = bg.window_idx, bg.compact_idx, bg.id_map
-    order = None
-    if block_order is not None:
-        order = tuple(int(b) for b in block_order)
-        idx = jnp.asarray(order, jnp.int32)
-        widx, cidx, ev, mask_f, idmap = (
-            jnp.take(a, idx, axis=0) for a in (widx, cidx, ev, mask_f, idmap))
+    ev, weighted, combine = _pallas_edges(bg, combine)
     eps, fuse_eps = _epilogue_arr(epilogue)
     out = fused_push_pallas(
-        vals, widx, cidx, ev, mask_f, idmap, eps,
+        vals, bg.window_idx, bg.compact_idx, ev, bg.n_edges, bg.id_map,
+        _visit_order(bg, block_order), eps,
         block_size=bg.block_size, local_budget=bg.local_budget, chunk=chunk,
         reduce=reduce, combine=combine, weighted=weighted,
-        fuse_epilogue=fuse_eps,
-        interpret=interpret if interpret is not None
-        else jax.default_backend() != "tpu")
-    if order is not None:
-        inv = [0] * bg.num_blocks
-        for j, b in enumerate(order):
-            inv[b] = j
-        out = jnp.take(out.reshape(bg.num_blocks, bg.block_size, d_pad),
-                       jnp.asarray(inv, jnp.int32), axis=0
-                       ).reshape(bg.num_blocks * bg.block_size, d_pad)
+        fuse_epilogue=fuse_eps, interpret=_interpret(interpret))
     out = out[: bg.n, :d]
     return out[:, 0] if squeeze else out
 

@@ -1,20 +1,26 @@
 """Pallas TPU kernel for the TOCAB blocked SpMM — the paper's hot loop.
 
-One grid step = one TOCAB subgraph (paper Alg. 4).  The ``BlockSpec`` pins the
+One grid row = one TOCAB subgraph (paper Alg. 4).  The ``BlockSpec`` pins the
 block's contiguous source-value window in VMEM — on TPU the residency the
 paper gets *probabilistically* from the GPU L2 is *guaranteed* by the DMA
-schedule.  Per-edge messages are gathered from the VMEM window and accumulated
-into a dense, compacted ``partials`` slab (local-ID compaction), which is
-written back as one coalesced burst.  The cross-block reduction (paper
-Fig. 5) happens outside the kernel as a flat segment-sum.
+schedule — while the block's edge chunks stream through the grid's second
+axis.  Per-edge messages are gathered from the VMEM window (one
+scalar-addressed row read per edge, the index in SMEM; see
+:mod:`repro.kernels.common`) and accumulated into a dense, compacted
+``partials`` slab (local-ID compaction), which is written back as one
+coalesced burst.  The cross-block reduction (paper Fig. 5) happens outside
+the kernel as a flat segment-sum.
 
 Two accumulation regimes (``mode``):
 
-* ``onehot`` — scatter expressed as ``onehotᵀ @ msgs`` small dense matmuls:
-  the MXU-native adaptation (irregular traffic → systolic work).  Preferred
-  when ``local_budget`` is small relative to the edge chunk.
-* ``scatter`` — in-VMEM ``.at[].add`` accumulation (VPU path); preferred for
-  very sparse blocks where the one-hot matmul would be mostly zeros.
+* ``onehot`` — scatter expressed as ``onehot @ msgs`` dense matmuls on the
+  MXU (``precision=HIGHEST``, so the 0/1 selection of f32 messages is
+  exact; the MXU's summation order is its own).  The irregular traffic
+  becomes systolic work; preferred when ``local_budget`` is small relative
+  to the edge chunk.
+* ``scatter`` — one row read-modify-write per edge in VMEM, in edge-slot
+  order; preferred for very sparse blocks where the one-hot matmul would
+  be mostly zeros.
 """
 from __future__ import annotations
 
@@ -23,55 +29,51 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.common import edge_chunks, lane_chunk, vmem_limit
 
 __all__ = ["tocab_spmm_pallas"]
 
-LANE = 128  # TPU lane width; last dims should be multiples of this
-
 
 def _kernel(
-    window_ref,  # (block_size, d)        VMEM — the value window
-    widx_ref,  # (1, edge_budget)         VMEM — src index within window
-    cidx_ref,  # (1, edge_budget)         VMEM — compacted dst local id
-    evals_ref,  # (1, edge_budget)        VMEM — edge values (0 for padding)
-    out_ref,  # (1, local_budget, d)      VMEM — dense partial slab
+    window_ref,  # (block_size, d)   VMEM — the value window
+    widx_ref,    # (1, chunk)        SMEM — src index within window
+    ev_ref,      # (1, chunk)        SMEM — edge values (0 for padding)
+    cidx_ref,    # (1, chunk)        VMEM (onehot) / SMEM (scatter)
+    out_ref,     # (local_budget, d) VMEM — dense partial slab
+    msgs_ref,    # (chunk, d)        VMEM — the chunk's messages
     *,
-    chunk: int,
     mode: str,
 ):
-    local_budget = out_ref.shape[1]
-    d = out_ref.shape[2]
-    edge_budget = widx_ref.shape[1]
-    acc = jnp.zeros((local_budget, d), jnp.float32)
-    num_chunks = -(-edge_budget // chunk)
+    chunk = widx_ref.shape[1]
 
-    def body(c, acc):
-        # Final ragged chunk: clamp the start so the slice stays in bounds,
-        # then zero the slots the previous chunk already covered (sum-only
-        # kernel, edge values carry the mask — a 0 contribution is a no-op).
-        start = jnp.minimum(c * chunk, edge_budget - chunk)
-        sl = pl.dslice(start, chunk)
-        widx = widx_ref[0, sl]
-        cidx = cidx_ref[0, sl]
-        ev = evals_ref[0, sl]
-        fresh = start + jax.lax.iota(jnp.int32, chunk) >= c * chunk
-        ev = jnp.where(fresh, ev, 0.0)
-        # gather from the VMEM-resident window (the confined random read)
-        msgs = jnp.take(window_ref[...], widx, axis=0) * ev[:, None]
-        if mode == "onehot":
-            # scatter == one-hot matmul: (local_budget, chunk) @ (chunk, d)
-            onehot = (
-                cidx[None, :] == jax.lax.iota(jnp.int32, local_budget)[:, None]
-            ).astype(jnp.float32)
-            acc = acc + jax.lax.dot(
-                onehot, msgs, preferred_element_type=jnp.float32
-            )
-        else:  # scatter (VPU)
-            acc = acc.at[cidx].add(msgs)
-        return acc
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
-    acc = jax.lax.fori_loop(0, num_chunks, body, acc, unroll=False)
-    out_ref[0, :, :] = acc.astype(out_ref.dtype)
+    # gather from the VMEM-resident window (the confined random read)
+    def gather(e, carry):
+        msgs_ref[pl.ds(e, 1), :] = (
+            window_ref[pl.ds(widx_ref[0, e], 1), :] * ev_ref[0, e])
+        return carry
+
+    jax.lax.fori_loop(0, chunk, gather, 0)
+
+    if mode == "onehot":
+        # scatter == one-hot matmul: (local_budget, chunk) @ (chunk, d)
+        rows = jax.lax.broadcasted_iota(jnp.int32, (out_ref.shape[0], chunk), 0)
+        onehot = (cidx_ref[...] == rows).astype(jnp.float32)
+        out_ref[...] += jax.lax.dot(
+            onehot, msgs_ref[...], precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    else:  # scatter (VPU)
+        def scatter(e, carry):
+            row = pl.ds(cidx_ref[0, e], 1)
+            out_ref[row, :] = out_ref[row, :] + msgs_ref[pl.ds(e, 1), :]
+            return carry
+
+        jax.lax.fori_loop(0, chunk, scatter, 0)
 
 
 @functools.partial(
@@ -79,7 +81,7 @@ def _kernel(
     static_argnames=("block_size", "local_budget", "chunk", "mode", "interpret"),
 )
 def tocab_spmm_pallas(
-    values,  # f32[num_blocks*block_size, d]  (padded)
+    values,  # f32[num_blocks*block_size, d]  (padded, d % 128 == 0)
     window_idx,  # i32[num_blocks, edge_budget]
     compact_idx,  # i32[num_blocks, edge_budget]
     edge_vals,  # f32[num_blocks, edge_budget] (0 where padded)
@@ -90,26 +92,45 @@ def tocab_spmm_pallas(
     mode: str = "onehot",
     interpret: bool = True,
 ):
-    """Phase-2 partials: returns f32[num_blocks, local_budget, d]."""
+    """Phase-2 partials: returns f32[num_blocks, local_budget, d].
+
+    ``chunk`` is rounded to a lane multiple dividing the padded edge budget
+    (padding slots carry edge value 0 and add nothing)."""
+    if mode not in ("onehot", "scatter"):
+        raise ValueError(f"unknown tocab_spmm mode {mode!r}")
     num_blocks, edge_budget = window_idx.shape
     d = values.shape[1]
     assert values.shape[0] == num_blocks * block_size, (
         f"values must be padded to num_blocks*block_size, got {values.shape}"
     )
-    # ragged edge budgets are fine — the kernel masks the final chunk
-    chunk = min(chunk, edge_budget)
+    chunk = lane_chunk(edge_budget, chunk)
+    need = 4 * (d * (2 * block_size + 2 * local_budget + chunk)
+                + (local_budget * chunk + 16 * chunk if mode == "onehot" else 0))
+    limit = vmem_limit(need, f"tocab_spmm ({mode}, {local_budget} rows × {d})")
+    widx, cidx, ev = (edge_chunks(a, chunk)
+                      for a in (window_idx, compact_idx, edge_vals))
 
-    grid = (num_blocks,)
+    def smem_chunk():
+        return pl.BlockSpec((None, None, 1, chunk), lambda b, c: (b, c, 0, 0),
+                            memory_space=pltpu.SMEM)
+
+    cidx_spec = (pl.BlockSpec((None, None, 1, chunk), lambda b, c: (b, c, 0, 0))
+                 if mode == "onehot" else smem_chunk())
     return pl.pallas_call(
-        functools.partial(_kernel, chunk=chunk, mode=mode),
-        grid=grid,
+        functools.partial(_kernel, mode=mode),
+        grid=(num_blocks, widx.shape[1]),
         in_specs=[
-            pl.BlockSpec((block_size, d), lambda b: (b, 0)),  # VMEM window
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
-            pl.BlockSpec((1, edge_budget), lambda b: (b, 0)),
+            pl.BlockSpec((block_size, d), lambda b, c: (b, 0)),  # VMEM window
+            smem_chunk(),
+            smem_chunk(),
+            cidx_spec,
         ],
-        out_specs=pl.BlockSpec((1, local_budget, d), lambda b: (b, 0, 0)),
+        out_specs=pl.BlockSpec((None, local_budget, d), lambda b, c: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_blocks, local_budget, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((chunk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=limit),
         interpret=interpret,
-    )(values, window_idx, compact_idx, edge_vals)
+        name="tocab_spmm",
+    )(values, widx, ev, cidx)

@@ -20,16 +20,13 @@ import jax.numpy as jnp
 
 from repro.core.partition import BlockedGraph
 from repro.core.tocab import reduce_partials
+from repro.kernels.common import LANE, roundup
 from repro.resilience import chaos as _chaos
 
-from .kernel import LANE, tocab_spmm_pallas
+from .kernel import tocab_spmm_pallas
 from .ref import tocab_spmm_ref
 
 __all__ = ["tocab_spmm", "tocab_spmm_partials", "LANE"]
-
-
-def _roundup(x: int, to: int) -> int:
-    return -(-x // to) * to
 
 
 @partial(
@@ -65,7 +62,7 @@ def tocab_spmm_partials(
     if squeeze:
         x = x[:, None]
     n, d = x.shape
-    d_pad = _roundup(d, LANE)
+    d_pad = roundup(d, LANE)
     rows_pad = bg.num_blocks * bg.block_size
     values = jnp.zeros((rows_pad, d_pad), jnp.float32)
     values = values.at[:n, :d].set(x.astype(jnp.float32))
@@ -87,9 +84,6 @@ def tocab_spmm_partials(
         values = jnp.take(
             values.reshape(bg.num_blocks, bg.block_size, d_pad), ids, axis=0
         ).reshape(len(block_ids) * bg.block_size, d_pad)
-
-    # ragged edge budgets are handled in-kernel (final chunk is masked)
-    chunk = max(1, min(chunk, bg.edge_budget))
 
     fn = tocab_spmm_ref if use_ref else partial(
         tocab_spmm_pallas, chunk=chunk, mode=mode, interpret=interpret

@@ -20,6 +20,7 @@ import sys
 import time
 from typing import Dict, Optional
 
+from repro.compile_cache import use_compile_cache
 from repro.core.graph import Graph
 
 from . import db as tune_db
@@ -245,6 +246,7 @@ def main(argv: Optional[list] = None) -> int:
         bad = sorted(set(args.impls) - {"slab", "fused"})
         if bad:
             ap.error(f"unknown impl(s) {bad}; expected slab/fused")
+    use_compile_cache()
     return args.fn(args)
 
 

@@ -37,7 +37,8 @@ _SUBPROC = textwrap.dedent("""
         make_dp_grad_fn, init_error_feedback, ring_all_reduce)
     from jax.experimental.shard_map import shard_map
 
-    mesh = jax.make_mesh((4, 2), ("pod", "data"))
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     out = {}
 
     # --- compressed DP grads ≈ exact grads ---
@@ -76,7 +77,8 @@ _SUBPROC = textwrap.dedent("""
     from repro.train import checkpoint as ckpt
     from repro.dist.elastic import make_mesh_for, reshard
     from jax.sharding import NamedSharding
-    big = jax.make_mesh((4, 2), ("data", "model"))
+    big = jax.make_mesh((4, 2), ("data", "model"),
+                        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     w = jax.device_put(jnp.arange(32.0).reshape(8, 4),
                        NamedSharding(big, P("data", "model")))
     with tempfile.TemporaryDirectory() as d:

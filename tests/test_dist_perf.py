@@ -21,7 +21,8 @@ _SUBPROC = textwrap.dedent("""
     from repro.dist.sharding import use_mesh_rules
     from repro.dist.collectives import distributed_topk
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     out = {}
     rng = np.random.default_rng(0)
 

@@ -306,8 +306,9 @@ def test_fused_obs_counters(setup):
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("chunk", [7, 100, 999999])
 def test_spmm_ragged_chunk(setup, chunk):
-    """The tile kernel used to require ``edge_budget % chunk == 0``; the
-    final ragged chunk is now masked in-kernel."""
+    """Any ``chunk`` gives the same partials, including ones that do not
+    divide the edge budget: the kernel rounds it to a lane multiple that
+    does."""
     from repro.kernels.tocab_spmm.ops import tocab_spmm
 
     g, dg, bg, _ = setup

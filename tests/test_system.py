@@ -100,7 +100,8 @@ def test_dryrun_machinery_small_mesh():
         from repro.dist.sharding import use_mesh_rules
         from repro.launch.cells import build_cell
         from repro.launch.hlo_analysis import parse_collectives, roofline_terms
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with use_mesh_rules(mesh):
             cell = build_cell("gat-cora", "full_graph_sm", mesh)
             compiled = jax.jit(cell.fn).lower(*cell.args).compile()
