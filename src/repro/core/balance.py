@@ -41,7 +41,7 @@ import numpy as np
 
 from repro.obs.metrics import registry as _obs
 
-from .partition import REDUCE_IDENTITY, BlockedGraph
+from .partition import REDUCE_IDENTITY, REDUCE_OPS, BlockedGraph
 
 __all__ = [
     "BIN_NAMES",
@@ -65,8 +65,6 @@ BIN_NAMES = ("sparse", "medium", "dense")
 #: the CPU-scale suite: rows shorter than a VPU sublane stay on the segmented
 #: reduce; rows long enough to amortize a tile matmul go dense.
 DEFAULT_THRESHOLDS = (4.0, 32.0)
-
-_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 
 def UNWEIGHTED(msgs, edge_vals):
@@ -285,7 +283,7 @@ def _reduce_msgs_scan(row_budget, cidx, mask, msgs, reduce, chunk: int = 256):
     value of the segment left open at each chunk boundary is the carry, and
     within a chunk the segmented prefix is an ``associative_scan``.  Segment
     totals are read at segment tails and scattered once per row."""
-    op = _OPS[reduce]
+    op = REDUCE_OPS[reduce]
     ident = jnp.asarray(REDUCE_IDENTITY[reduce], msgs.dtype)
     k, eb = cidx.shape
     tail = msgs.shape[2:]
@@ -531,7 +529,7 @@ def _push_window_chunked(bg, widx, mask, msgs, reduce, chunk: int = 256):
     final write-back is a pure reshape — no global scatter)."""
     from .tocab import segment_reduce
 
-    op = _OPS[reduce]
+    op = REDUCE_OPS[reduce]
     k, eb = widx.shape
     tail = msgs.shape[2:]
     chunk = _pick_chunk(eb, chunk)

@@ -16,12 +16,13 @@ the TPU analogue of the paper's TWC shape regularization.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.common import roundup
 
 from .graph import Graph, GraphValidationError, graph_fingerprint, \
     validate_graph
@@ -37,10 +38,8 @@ REDUCE_IDENTITY = {
     "min": float("inf"),
     "max": float("-inf"),
 }
-
-
-def _roundup(x: int, to: int) -> int:
-    return int(math.ceil(max(x, 1) / to) * to)
+# The reduction op itself, elementwise, for each name above.
+REDUCE_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 
 @jax.tree_util.register_dataclass
@@ -119,7 +118,7 @@ def choose_block_size(
     window (→ up to 2²⁰ fp32 values), yielding *far fewer* subgraphs — the
     paper's own argument against CuSha's tiny shards, taken further."""
     bs = min(max(align, fast_mem_bytes // value_bytes), max(n, align))
-    return _roundup(bs, align)
+    return roundup(bs, align)
 
 
 def build_blocked(
@@ -176,7 +175,7 @@ def build_blocked(
     vals = None if g.vals is None else g.vals[order]
 
     edge_counts = np.bincount(blk, minlength=num_blocks).astype(np.int64)
-    edge_budget = _roundup(int(edge_counts.max(initial=1)), pad_edges_to)
+    edge_budget = roundup(int(edge_counts.max(initial=1)), pad_edges_to)
 
     # Local-ID compaction: within each block, unique compact-side vertices in
     # sorted order get ids 0..n_local-1 (paper Fig. 4).
@@ -193,7 +192,7 @@ def build_blocked(
     n_local = np.zeros(num_blocks, dtype=np.int64)
     if blk.shape[0]:
         np.maximum.at(n_local, blk, local_id + 1)
-    local_budget = _roundup(int(n_local.max(initial=1)), pad_locals_to)
+    local_budget = roundup(int(n_local.max(initial=1)), pad_locals_to)
 
     # Padded slabs are flattened and indexed with int32 downstream (the
     # phase-3 segment reduce, the Pallas kernels' id maps) — overflow here

@@ -6,14 +6,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import roundup
+
 from .kernel import embedding_bag_pallas
 from .ref import embedding_bag_ref
 
 __all__ = ["embedding_bag"]
-
-
-def _roundup(x: int, to: int) -> int:
-    return -(-x // to) * to
 
 
 @partial(
@@ -39,9 +37,9 @@ def embedding_bag(
     if mode == "mean":
         denom = jnp.maximum(weights.sum(axis=1, keepdims=True), 1e-9)
         weights = weights / denom
-    rows_per_block = min(rows_per_block, _roundup(V, 8))
-    Vp = _roundup(V, rows_per_block)
-    Bp = _roundup(B, min(bag_tile, _roundup(B, 8)))
+    rows_per_block = min(rows_per_block, roundup(V, 8))
+    Vp = roundup(V, rows_per_block)
+    Bp = roundup(B, min(bag_tile, roundup(B, 8)))
     bag_tile = min(bag_tile, Bp)
     tbl = jnp.zeros((Vp, d), table.dtype).at[:V].set(table)
     idx = jnp.zeros((Bp, L), indices.dtype).at[:B].set(indices)

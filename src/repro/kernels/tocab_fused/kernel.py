@@ -42,12 +42,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.partition import REDUCE_IDENTITY
+from repro.core.partition import REDUCE_IDENTITY, REDUCE_OPS
 from repro.kernels.common import edge_chunks, lane_chunk, vmem_limit
 
 __all__ = ["fused_pull_pallas", "fused_push_pallas"]
-
-_OPS = {"sum": jnp.add, "min": jnp.minimum, "max": jnp.maximum}
 
 
 def _chunk_edges(n_edges, c, chunk: int):
@@ -90,7 +88,7 @@ def _fused_pull_kernel(
     chunk = widx_ref.shape[1]
     tile_rows = tile_ref.shape[0]
     ident = REDUCE_IDENTITY[reduce]
-    op = _OPS[reduce]
+    op = REDUCE_OPS[reduce]
 
     @pl.when((b == 0) & (c == 0))
     def _init_tile():
@@ -239,7 +237,7 @@ def _fused_push_kernel(
     b, c = pl.program_id(0), pl.program_id(1)
     nc = pl.num_programs(1)
     chunk = widx_ref.shape[1]
-    op = _OPS[reduce]
+    op = REDUCE_OPS[reduce]
 
     @pl.when((b == 0) & (c == 0))
     def _load_values():

@@ -106,7 +106,10 @@ def tocab_spmm_pallas(
     chunk = lane_chunk(edge_budget, chunk)
     need = 4 * (d * (2 * block_size + 2 * local_budget + chunk)
                 + (local_budget * chunk + 16 * chunk if mode == "onehot" else 0))
-    limit = vmem_limit(need, f"tocab_spmm ({mode}, {local_budget} rows × {d})")
+    # double-buffered SMEM index rows: widx and ev, and cidx when scattering
+    smem = 8 * chunk * (2 if mode == "onehot" else 3)
+    limit = vmem_limit(need, f"tocab_spmm ({mode}, {local_budget} rows × {d})",
+                       smem)
     widx, cidx, ev = (edge_chunks(a, chunk)
                       for a in (window_idx, compact_idx, edge_vals))
 

@@ -131,3 +131,17 @@ def test_fused_push_too_big_for_smem_raises(one_chip):
             _spec(one_chip, (nb,), i32), _spec(one_chip, (nb, lb), i32),
             _spec(one_chip, (nb,), i32), _spec(one_chip, (1, 2)),
             block_size=bs, local_budget=lb, interpret=False)
+
+
+def test_tocab_spmm_chunk_too_big_for_smem_raises(one_chip):
+    """A ``chunk`` whose double-buffered SMEM index rows (window and edge
+    value, plus compact id when scattering) exceed SMEM is refused at the
+    entry point, not inside the compiler."""
+    nb, bs, eb, lb = 4, 2048, 65536, 1024
+    i32 = jnp.int32
+    with pytest.raises(ValueError, match="SMEM"):
+        tocab_spmm_pallas.lower(
+            _spec(one_chip, (nb * bs, LANE)), _spec(one_chip, (nb, eb), i32),
+            _spec(one_chip, (nb, eb), i32), _spec(one_chip, (nb, eb)),
+            block_size=bs, local_budget=lb, chunk=eb, mode="scatter",
+            interpret=False)
