@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 __all__ = [
     "Graph",
     "DeviceGraph",
@@ -208,17 +210,20 @@ class DeviceGraph:
 
     @classmethod
     def from_host(cls, g: Graph) -> "DeviceGraph":
+        """Copy ``g`` to the device; the copies are the ``obs`` span
+        ``device_graph.place``."""
         src, dst = g.edges()
-        return cls(
-            n=g.n,
-            src=jnp.asarray(src, jnp.int32),
-            dst=jnp.asarray(dst, jnp.int32),
-            rowptr=jnp.asarray(g.rowptr, jnp.int32),
-            out_degree=jnp.asarray(g.out_degree, jnp.int32),
-            in_degree=jnp.asarray(g.in_degree, jnp.int32),
-            vals=None if g.vals is None else jnp.asarray(g.vals, jnp.float32),
-            fingerprint=graph_fingerprint(g),
-        )
+        out_degree, in_degree = g.out_degree, g.in_degree
+        with obs.span("device_graph.place"):
+            arrays = dict(
+                src=jnp.asarray(src, jnp.int32),
+                dst=jnp.asarray(dst, jnp.int32),
+                rowptr=jnp.asarray(g.rowptr, jnp.int32),
+                out_degree=jnp.asarray(out_degree, jnp.int32),
+                in_degree=jnp.asarray(in_degree, jnp.int32),
+                vals=None if g.vals is None else jnp.asarray(g.vals, jnp.float32),
+            )
+        return cls(n=g.n, fingerprint=graph_fingerprint(g), **arrays)
 
 
 def from_edges(

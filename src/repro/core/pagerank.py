@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from .balance import UNWEIGHTED as _unweighted
 from .graph import DeviceGraph
 from .partition import BlockedGraph
@@ -104,20 +105,25 @@ def pagerank(
     fused→slab→reference degradation ladder: a kernel-dispatch failure at
     trace time degrades the engine instead of crashing the run, and the
     memoized verdict (``repro.resilience.degrade``) pins later calls for
-    this graph straight to the working rung."""
+    this graph straight to the working rung.
+
+    The call is an ``obs`` span ``pagerank`` (resolve and dispatch; the
+    solve runs on after it returns), and each step of the compiled loop
+    is the named scope ``pagerank.step``."""
     from repro.resilience import degrade
 
-    obj = bg if bg is not None else dg
-    rs = tocab.resolve_schedule(obj, schedule, workload="pagerank")
-    ri = tocab.resolve_impl(obj, impl, workload="pagerank")
-    rs, ri = tocab._reconcile_fused(rs, ri, schedule, impl)
-    allow = degrade.fallback_allowed(impl, allow_fallback)
-    if allow and bg is not None and variant in ("gc-pull", "gc-push"):
-        site = "tocab_pull" if variant == "gc-pull" else "tocab_push"
-        ri = degrade.apply_verdict(bg.fingerprint, site, ri)
-    return _pagerank_jit(
-        dg, bg, variant, damping, tol, max_iters, handle_dangling, rs, ri,
-        allow)
+    with obs.span("pagerank"):
+        obj = bg if bg is not None else dg
+        rs = tocab.resolve_schedule(obj, schedule, workload="pagerank")
+        ri = tocab.resolve_impl(obj, impl, workload="pagerank")
+        rs, ri = tocab._reconcile_fused(rs, ri, schedule, impl)
+        allow = degrade.fallback_allowed(impl, allow_fallback)
+        if allow and bg is not None and variant in ("gc-pull", "gc-push"):
+            site = "tocab_pull" if variant == "gc-pull" else "tocab_push"
+            ri = degrade.apply_verdict(bg.fingerprint, site, ri)
+        return _pagerank_jit(
+            dg, bg, variant, damping, tol, max_iters, handle_dangling, rs,
+            ri, allow)
 
 
 @partial(
@@ -148,11 +154,12 @@ def _pagerank_jit(
 
     def body(state):
         rank, _, it = state
-        new_rank = pagerank_iteration(
-            variant, dg, bg, rank, dg.out_degree, damping, handle_dangling,
-            schedule, impl, allow_fallback,
-        )
-        return new_rank, jnp.abs(new_rank - rank).sum(), it + 1
+        with jax.named_scope("pagerank.step"):
+            new_rank = pagerank_iteration(
+                variant, dg, bg, rank, dg.out_degree, damping,
+                handle_dangling, schedule, impl, allow_fallback,
+            )
+            return new_rank, jnp.abs(new_rank - rank).sum(), it + 1
 
     rank, _, iters = jax.lax.while_loop(cond, body, (rank0, jnp.inf, 0))
     return rank, iters

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels.common import roundup
 
 from .graph import Graph, GraphValidationError, graph_fingerprint, \
@@ -151,111 +152,122 @@ def build_blocked(
     corrupting the blocked slabs.  Independently of ``validate``, padded
     slab sizes are always checked against int32 addressing.
     """
-    assert direction in ("pull", "push")
-    if validate is not None:
-        validate_graph(g, level=validate)
-    if block_size is None:
-        block_size = choose_block_size(g.n, fast_mem_bytes=fast_mem_bytes)
-    src, dst = g.edges()
-    src = src.astype(np.int64)
-    dst = dst.astype(np.int64)
-    if direction == "pull":
-        window_g, compact_g = src, dst  # gather from src window, compact dst
-    else:
-        window_g, compact_g = dst, src  # scatter to dst window, compact src
+    with obs.span("build_blocked", direction=direction, n=g.n, m=g.m):
+        assert direction in ("pull", "push")
+        if validate is not None:
+            validate_graph(g, level=validate)
+        if block_size is None:
+            block_size = choose_block_size(g.n, fast_mem_bytes=fast_mem_bytes)
+        src, dst = g.edges()
+        src = src.astype(np.int64)
+        dst = dst.astype(np.int64)
+        if direction == "pull":
+            window_g, compact_g = src, dst  # gather from src window, compact dst
+        else:
+            window_g, compact_g = dst, src  # scatter to dst window, compact src
 
-    num_blocks = max(1, -(-g.n // block_size))
-    blk = window_g // block_size
+        num_blocks = max(1, -(-g.n // block_size))
+        blk = window_g // block_size
 
-    # Sort edges by (block, compact-global) — gives blocked CSR with the
-    # compacted side contiguous, which both makes local-ID assignment a
-    # run-length pass and keeps the scatter side sorted for the kernels.
-    order = np.lexsort((compact_g, blk))
-    blk, window_g, compact_g = blk[order], window_g[order], compact_g[order]
-    vals = None if g.vals is None else g.vals[order]
+        # Sort edges by (block, compact-global) — gives blocked CSR with the
+        # compacted side contiguous, which both makes local-ID assignment a
+        # run-length pass and keeps the scatter side sorted for the kernels.
+        with obs.span("build_blocked.sort"):
+            order = np.lexsort((compact_g, blk))
+            blk, window_g, compact_g = blk[order], window_g[order], compact_g[order]
+            vals = None if g.vals is None else g.vals[order]
 
-    edge_counts = np.bincount(blk, minlength=num_blocks).astype(np.int64)
-    edge_budget = roundup(int(edge_counts.max(initial=1)), pad_edges_to)
+        with obs.span("build_blocked.fill"):
+            edge_counts = np.bincount(blk, minlength=num_blocks).astype(np.int64)
+            edge_budget = roundup(int(edge_counts.max(initial=1)), pad_edges_to)
 
-    # Local-ID compaction: within each block, unique compact-side vertices in
-    # sorted order get ids 0..n_local-1 (paper Fig. 4).
-    new_run = np.ones(blk.shape[0], dtype=bool)
-    if blk.shape[0] > 1:
-        new_run[1:] = (blk[1:] != blk[:-1]) | (compact_g[1:] != compact_g[:-1])
-    run_id = np.cumsum(new_run) - 1  # global run index
-    block_start_run = np.zeros(num_blocks + 1, dtype=np.int64)
-    # run index at the first edge of each block:
-    first_edge = np.cumsum(np.concatenate([[0], edge_counts]))[:-1]
-    has_edges = edge_counts > 0
-    block_start_run[:-1][has_edges] = run_id[first_edge[has_edges]]
-    local_id = run_id - np.repeat(block_start_run[:-1], edge_counts)
-    n_local = np.zeros(num_blocks, dtype=np.int64)
-    if blk.shape[0]:
-        np.maximum.at(n_local, blk, local_id + 1)
-    local_budget = roundup(int(n_local.max(initial=1)), pad_locals_to)
+            # Local-ID compaction: within each block, unique compact-side
+            # vertices in sorted order get ids 0..n_local-1 (paper Fig. 4).
+            new_run = np.ones(blk.shape[0], dtype=bool)
+            if blk.shape[0] > 1:
+                new_run[1:] = ((blk[1:] != blk[:-1])
+                               | (compact_g[1:] != compact_g[:-1]))
+            run_id = np.cumsum(new_run) - 1  # global run index
+            block_start_run = np.zeros(num_blocks + 1, dtype=np.int64)
+            # run index at the first edge of each block:
+            first_edge = np.cumsum(np.concatenate([[0], edge_counts]))[:-1]
+            has_edges = edge_counts > 0
+            block_start_run[:-1][has_edges] = run_id[first_edge[has_edges]]
+            local_id = run_id - np.repeat(block_start_run[:-1], edge_counts)
+            n_local = np.zeros(num_blocks, dtype=np.int64)
+            if blk.shape[0]:
+                np.maximum.at(n_local, blk, local_id + 1)
+            local_budget = roundup(int(n_local.max(initial=1)), pad_locals_to)
 
-    # Padded slabs are flattened and indexed with int32 downstream (the
-    # phase-3 segment reduce, the Pallas kernels' id maps) — overflow here
-    # would wrap silently at runtime, so it is always a hard error.
-    int32_max = np.iinfo(np.int32).max
-    for what, size in (("edge", num_blocks * edge_budget),
-                       ("partial", num_blocks * local_budget)):
-        if size > int32_max:
-            raise GraphValidationError(
-                "budget_overflow",
-                f"flat {what} slab has {size} entries "
-                f"(num_blocks={num_blocks}), exceeding int32 addressing")
+            # Padded slabs are flattened and indexed with int32 downstream
+            # (the phase-3 segment reduce, the Pallas kernels' id maps) —
+            # overflow here would wrap silently at runtime, so it is always a
+            # hard error.
+            int32_max = np.iinfo(np.int32).max
+            for what, size in (("edge", num_blocks * edge_budget),
+                               ("partial", num_blocks * local_budget)):
+                if size > int32_max:
+                    raise GraphValidationError(
+                        "budget_overflow",
+                        f"flat {what} slab has {size} entries "
+                        f"(num_blocks={num_blocks}), exceeding int32 addressing")
 
-    # --- fill padded slabs ---
-    shape_e = (num_blocks, edge_budget)
-    window_idx = np.zeros(shape_e, dtype=np.int32)
-    compact_idx = np.zeros(shape_e, dtype=np.int32)
-    edge_mask = np.zeros(shape_e, dtype=bool)
-    edge_perm = np.full(shape_e, g.m, dtype=np.int32)
-    edge_vals = None if vals is None else np.zeros(shape_e, dtype=np.float32)
-    id_map = np.full((num_blocks, local_budget), g.n, dtype=np.int32)
+            # --- fill padded slabs ---
+            shape_e = (num_blocks, edge_budget)
+            window_idx = np.zeros(shape_e, dtype=np.int32)
+            compact_idx = np.zeros(shape_e, dtype=np.int32)
+            edge_mask = np.zeros(shape_e, dtype=bool)
+            edge_perm = np.full(shape_e, g.m, dtype=np.int32)
+            edge_vals = (None if vals is None
+                         else np.zeros(shape_e, dtype=np.float32))
+            id_map = np.full((num_blocks, local_budget), g.n, dtype=np.int32)
 
-    slot = np.arange(blk.shape[0]) - np.repeat(first_edge, edge_counts)
-    window_idx[blk, slot] = (window_g - blk * block_size).astype(np.int32)
-    compact_idx[blk, slot] = local_id.astype(np.int32)
-    edge_mask[blk, slot] = True
-    edge_perm[blk, slot] = order.astype(np.int32)  # original edge index
-    if edge_vals is not None:
-        edge_vals[blk, slot] = vals
-    id_map[blk, local_id] = compact_g.astype(np.int32)
+            slot = np.arange(blk.shape[0]) - np.repeat(first_edge, edge_counts)
+            window_idx[blk, slot] = (window_g - blk * block_size).astype(np.int32)
+            compact_idx[blk, slot] = local_id.astype(np.int32)
+            edge_mask[blk, slot] = True
+            edge_perm[blk, slot] = order.astype(np.int32)  # original edge index
+            if edge_vals is not None:
+                edge_vals[blk, slot] = vals
+            id_map[blk, local_id] = compact_g.astype(np.int32)
 
-    # Distinct window-side vertices per block — the reduction-row count of
-    # the push direction (pull reduces over the compacted side, n_local).
-    n_window = np.zeros(num_blocks, dtype=np.int64)
-    if blk.shape[0]:
-        pair = np.unique(blk * np.int64(g.n + 1) + window_g)
-        np.add.at(n_window, (pair // (g.n + 1)).astype(np.int64), 1)
+            # Distinct window-side vertices per block — the reduction-row
+            # count of the push direction (pull reduces over the compacted
+            # side, n_local).
+            n_window = np.zeros(num_blocks, dtype=np.int64)
+            if blk.shape[0]:
+                pair = np.unique(blk * np.int64(g.n + 1) + window_g)
+                np.add.at(n_window, (pair // (g.n + 1)).astype(np.int64), 1)
 
-    schedule = None
-    if classify:
-        from .balance import make_schedule  # deferred import (cycle-free)
+        schedule = None
+        if classify:
+            from .balance import make_schedule  # deferred import (cycle-free)
 
-        rows = n_local if direction == "pull" else n_window
-        schedule = make_schedule(edge_counts, rows, thresholds=bin_thresholds,
-                                 n_compact_rows=n_local)
+            rows = n_local if direction == "pull" else n_window
+            schedule = make_schedule(edge_counts, rows, thresholds=bin_thresholds,
+                                     n_compact_rows=n_local)
 
-    return BlockedGraph(
-        n=g.n,
-        m=g.m,
-        direction=direction,
-        block_size=int(block_size),
-        num_blocks=int(num_blocks),
-        edge_budget=int(edge_budget),
-        local_budget=int(local_budget),
-        window_idx=jnp.asarray(window_idx),
-        compact_idx=jnp.asarray(compact_idx),
-        edge_mask=jnp.asarray(edge_mask),
-        id_map=jnp.asarray(id_map),
-        n_local=jnp.asarray(n_local, jnp.int32),
-        n_edges=jnp.asarray(edge_counts, jnp.int32),
-        edge_perm=jnp.asarray(edge_perm),
-        edge_vals=None if edge_vals is None else jnp.asarray(edge_vals),
-        n_window=jnp.asarray(n_window, jnp.int32),
-        schedule=schedule,
-        fingerprint=graph_fingerprint(g),
-    )
+        with obs.span("build_blocked.place"):
+            slabs = dict(
+                window_idx=jnp.asarray(window_idx),
+                compact_idx=jnp.asarray(compact_idx),
+                edge_mask=jnp.asarray(edge_mask),
+                id_map=jnp.asarray(id_map),
+                n_local=jnp.asarray(n_local, jnp.int32),
+                n_edges=jnp.asarray(edge_counts, jnp.int32),
+                edge_perm=jnp.asarray(edge_perm),
+                edge_vals=None if edge_vals is None else jnp.asarray(edge_vals),
+                n_window=jnp.asarray(n_window, jnp.int32),
+            )
+        return BlockedGraph(
+            n=g.n,
+            m=g.m,
+            direction=direction,
+            block_size=int(block_size),
+            num_blocks=int(num_blocks),
+            edge_budget=int(edge_budget),
+            local_budget=int(local_budget),
+            schedule=schedule,
+            fingerprint=graph_fingerprint(g),
+            **slabs,
+        )

@@ -17,6 +17,16 @@ All engines support ``sum`` / ``min`` / ``max`` semirings so that PageRank,
 SpMV (sum×mul), BFS/SSSP (min-plus) and frontier propagation (max/or) share
 one code path — this is the framework's "programmers only write pull/push
 operators" surface (paper §3.3 last paragraph).
+
+The uniform slab paths name their phases with ``jax.named_scope``, so the
+compiled operations carry the names into a profiler trace:
+
+* ``tocab.gather`` — per-edge messages: the reads of ``values`` (or of the
+  block's slab), the combine and the padding mask;
+* ``tocab.partials`` — the per-block compacted slab: pull's phase-2 segment
+  reduce into it, push's fill of it through ``id_map``;
+* ``tocab.reduce`` — into the global vertex array: phase 3
+  (:func:`reduce_partials`), push's reduce into the destination windows.
 """
 from __future__ import annotations
 
@@ -44,52 +54,17 @@ __all__ = [
     "blocked_edge_values",
     "tocab_gather_src",
     "reduce_partials",
-    "timed",
 ]
 
 
-def _record_engine(engine: str, direction: str, blocks: int, edges: int):
-    """Trace-time telemetry: fires once per (re)trace — shapes and block
-    counts are static, so this is jit-safe and costs nothing at runtime.
-    A growing ``engine_traces`` count on a steady workload is itself a
-    signal (retrace churn)."""
+def _record_engine(engine: str, direction: str):
+    """Trace-time telemetry: fires once per (re)trace, so it is jit-safe and
+    costs nothing at runtime.  A growing ``engine_traces`` count on a steady
+    workload is itself a signal (retrace churn)."""
     _obs.counter(
         "tocab.engine_traces", "engine (re)traces by name/direction"
     ).inc(engine=engine, direction=direction)
-    _obs.gauge("tocab.blocks", "subgraphs per blocked engine trace").set(
-        blocks, engine=engine)
-    _obs.gauge("tocab.edges", "edges per engine trace").set(
-        edges, engine=engine)
 
-
-def _block_tree(out):
-    """``block_until_ready`` over an arbitrary engine return value: arrays,
-    tuples/dicts of arrays, or leaves without the method (ints, numpy)."""
-    return jax.tree_util.tree_map(
-        lambda leaf: leaf.block_until_ready()
-        if hasattr(leaf, "block_until_ready") else leaf,
-        out,
-    )
-
-
-def timed(engine_fn, graph, *args, engine: str = None, **kw):
-    """Synchronously run one engine call, recording wall time and edges/s.
-
-    ``graph`` is the DeviceGraph / BlockedGraph first argument; edges come
-    from its static ``m``.  The engine may return a bare array or any pytree
-    (e.g. ``(rank, iters)``) — every leaf is blocked on before the clock
-    stops.  Returns the (blocked-until-ready) result."""
-    import time
-
-    name = engine or getattr(engine_fn, "__name__", "engine")
-    t0 = time.perf_counter()
-    out = _block_tree(engine_fn(graph, *args, **kw))
-    dt = time.perf_counter() - t0
-    _obs.histogram("tocab.call_seconds", "engine wall time").observe(
-        dt, engine=name)
-    _obs.gauge("tocab.edges_per_s", "engine throughput").set(
-        graph.m / max(dt, 1e-12), engine=name)
-    return out
 
 _SEG_FNS = {
     "sum": jax.ops.segment_sum,
@@ -138,7 +113,7 @@ def baseline_pull(
 
     Flat segment reduce by destination — the unblocked hand-optimized
     reference (random reads of ``values`` span the full array)."""
-    _record_engine("baseline_pull", "pull", 1, dg.m)
+    _record_engine("baseline_pull", "pull")
     mask = jnp.ones(dg.src.shape, dtype=bool)
     msgs = _edge_messages(values, dg.src, dg.vals, mask, reduce, combine)
     return segment_reduce(msgs, dg.dst, dg.n, reduce)
@@ -154,7 +129,7 @@ def baseline_push(
     """Push direction: scatter values[src] to every out-neighbour.  On TPU
     there are no atomics — the scatter is realized as a segment reduce, i.e.
     push ≡ pull with the read side sequential (src-sorted edges)."""
-    _record_engine("baseline_push", "push", 1, dg.m)
+    _record_engine("baseline_push", "push")
     mask = jnp.ones(dg.src.shape, dtype=bool)
     msgs = _edge_messages(values, dg.src, dg.vals, mask, reduce, combine)
     return segment_reduce(msgs, dg.dst, dg.n, reduce)
@@ -173,7 +148,7 @@ def cb_pull(
     """Column blocking only: gathers are window-confined but every block
     writes partials at global width (repeated sparse access to ``sums``)."""
     assert bg.direction == "pull"
-    _record_engine("cb_pull", "pull", bg.num_blocks, bg.m)
+    _record_engine("cb_pull", "pull")
     src_global = bg.window_idx + bg.window_lo()[:, None]
     msgs = _edge_messages(values, src_global, bg.edge_vals, bg.edge_mask, reduce, combine)
     # id_map lookup per edge: id_map[b, compact_idx[b,e]]
@@ -215,19 +190,23 @@ def tocab_pull_partials(
     Gathers hit only the block's contiguous source window; scatters hit only
     the dense local partial slab — both fast-memory resident on TPU."""
     assert bg.direction == "pull"
-    src_global = bg.window_idx + bg.window_lo()[:, None]
-    msgs = _edge_messages(values, src_global, bg.edge_vals, bg.edge_mask, reduce, combine)
-    flat_idx = (
-        bg.compact_idx + jnp.arange(bg.num_blocks, dtype=jnp.int32)[:, None] * bg.local_budget
-    )
-    tail = msgs.shape[2:]
-    partials = segment_reduce(
-        msgs.reshape((-1,) + tail),
-        flat_idx.reshape(-1),
-        bg.flat_partial_size,
-        reduce,
-    )
-    return partials.reshape((bg.num_blocks, bg.local_budget) + tail)
+    with jax.named_scope("tocab.gather"):
+        src_global = bg.window_idx + bg.window_lo()[:, None]
+        msgs = _edge_messages(values, src_global, bg.edge_vals, bg.edge_mask,
+                              reduce, combine)
+    with jax.named_scope("tocab.partials"):
+        flat_idx = (
+            bg.compact_idx
+            + jnp.arange(bg.num_blocks, dtype=jnp.int32)[:, None] * bg.local_budget
+        )
+        tail = msgs.shape[2:]
+        partials = segment_reduce(
+            msgs.reshape((-1,) + tail),
+            flat_idx.reshape(-1),
+            bg.flat_partial_size,
+            reduce,
+        )
+        return partials.reshape((bg.num_blocks, bg.local_budget) + tail)
 
 
 def reduce_partials(bg: BlockedGraph, partials: jnp.ndarray, reduce: str = "sum"):
@@ -235,14 +214,15 @@ def reduce_partials(bg: BlockedGraph, partials: jnp.ndarray, reduce: str = "sum"
     into the global result.  One flat segment reduce keyed by ``id_map`` —
     XLA lowers it to a vectorized single pass; on a sharded mesh the same op
     becomes a reduce-scatter over the destination axis."""
-    tail = partials.shape[2:]
-    out = segment_reduce(
-        partials.reshape((-1,) + tail),
-        bg.id_map.reshape(-1),
-        bg.n + 1,  # padded id_map entries point at segment n → dropped
-        reduce,
-    )
-    return out[:-1]
+    with jax.named_scope("tocab.reduce"):
+        tail = partials.shape[2:]
+        out = segment_reduce(
+            partials.reshape((-1,) + tail),
+            bg.id_map.reshape(-1),
+            bg.n + 1,  # padded id_map entries point at segment n → dropped
+            reduce,
+        )
+        return out[:-1]
 
 
 def resolve_schedule(bg, schedule: str, workload: str = "spmv") -> str:
@@ -343,7 +323,7 @@ def _tocab_pull_jit(
                              dense_impl=dense_impl)
     if schedule != "uniform":
         raise ValueError(f"unknown schedule {schedule!r}")
-    _record_engine("tocab_pull", "pull", bg.num_blocks, bg.m)
+    _record_engine("tocab_pull", "pull")
     partials = tocab_pull_partials(bg, values, reduce, combine)
     return reduce_partials(bg, partials, reduce)
 
@@ -389,7 +369,7 @@ def tocab_pull(
         chaos.maybe_raise("kernel.tocab_fused")
         from repro.kernels.tocab_fused import fused_pull
 
-        _record_engine("tocab_pull_fused", "pull", bg.num_blocks, bg.m)
+        _record_engine("tocab_pull_fused", "pull")
         return fused_pull(bg, values, reduce, combine, epilogue)
 
     def _slab():
@@ -402,7 +382,7 @@ def tocab_pull(
     def _reference():
         # eager uniform dataflow, no jax.jit anywhere on the way down —
         # survives backend lowering/compile failures by construction
-        _record_engine("tocab_pull_reference", "pull", bg.num_blocks, bg.m)
+        _record_engine("tocab_pull_reference", "pull")
         partials = tocab_pull_partials(bg, values, reduce, combine)
         return _slab_epilogue(reduce_partials(bg, partials, reduce),
                               reduce, epilogue)
@@ -438,36 +418,42 @@ def _tocab_push_uniform(
 ):
     """Uniform push body — shared by the jitted wrapper above and the
     eager ``reference`` rung of the degradation ladder."""
-    _record_engine(engine, "push", bg.num_blocks, bg.m)
+    _record_engine(engine, "push")
     # Gather each unique source's value once per block (the data-reuse win).
-    block_contrib = jnp.take(values, bg.id_map, axis=0, mode="fill", fill_value=0)
-    msgs = jnp.take_along_axis(
-        block_contrib,
-        bg.compact_idx if block_contrib.ndim == 2 else bg.compact_idx[..., None],
-        axis=1,
-    )
-    ev = bg.edge_vals
-    if ev is not None:
-        while ev.ndim < msgs.ndim:
-            ev = ev[..., None]
-    if combine is not None:
-        msgs = combine(msgs, ev)
-    elif ev is not None:
-        msgs = msgs * ev
-    ident = jnp.asarray(REDUCE_IDENTITY[reduce], msgs.dtype)
-    mask = bg.edge_mask if msgs.ndim == bg.edge_mask.ndim else bg.edge_mask[..., None]
-    msgs = jnp.where(mask, msgs, ident)
+    with jax.named_scope("tocab.partials"):
+        block_contrib = jnp.take(values, bg.id_map, axis=0, mode="fill",
+                                 fill_value=0)
+    with jax.named_scope("tocab.gather"):
+        msgs = jnp.take_along_axis(
+            block_contrib,
+            bg.compact_idx if block_contrib.ndim == 2
+            else bg.compact_idx[..., None],
+            axis=1,
+        )
+        ev = bg.edge_vals
+        if ev is not None:
+            while ev.ndim < msgs.ndim:
+                ev = ev[..., None]
+        if combine is not None:
+            msgs = combine(msgs, ev)
+        elif ev is not None:
+            msgs = msgs * ev
+        ident = jnp.asarray(REDUCE_IDENTITY[reduce], msgs.dtype)
+        mask = (bg.edge_mask if msgs.ndim == bg.edge_mask.ndim
+                else bg.edge_mask[..., None])
+        msgs = jnp.where(mask, msgs, ident)
     # Scatter into the (disjoint) per-block destination windows.
-    dst_global = bg.window_idx + bg.window_lo()[:, None]
-    dst_global = jnp.where(bg.edge_mask, dst_global, bg.n)
-    tail = msgs.shape[2:]
-    out = segment_reduce(
-        msgs.reshape((-1,) + tail),
-        dst_global.reshape(-1),
-        bg.n + 1,
-        reduce,
-    )
-    return out[:-1]
+    with jax.named_scope("tocab.reduce"):
+        dst_global = bg.window_idx + bg.window_lo()[:, None]
+        dst_global = jnp.where(bg.edge_mask, dst_global, bg.n)
+        tail = msgs.shape[2:]
+        out = segment_reduce(
+            msgs.reshape((-1,) + tail),
+            dst_global.reshape(-1),
+            bg.n + 1,
+            reduce,
+        )
+        return out[:-1]
 
 
 def tocab_push(
@@ -501,7 +487,7 @@ def tocab_push(
         chaos.maybe_raise("kernel.tocab_fused")
         from repro.kernels.tocab_fused import fused_push
 
-        _record_engine("tocab_push_fused", "push", bg.num_blocks, bg.m)
+        _record_engine("tocab_push_fused", "push")
         return fused_push(bg, values, reduce, combine, epilogue)
 
     def _slab():
@@ -531,22 +517,24 @@ def blocked_edge_values(bg: BlockedGraph, flat_vals: jnp.ndarray) -> jnp.ndarray
 
 def _edge_reduce_uniform(bg: BlockedGraph, flat_edge_vals, reduce: str):
     """Uniform edge-reduce body (eager; shared by slab and reference)."""
-    vals = blocked_edge_values(bg, flat_edge_vals)
-    ident = jnp.asarray(REDUCE_IDENTITY[reduce], vals.dtype)
-    mask = bg.edge_mask
-    while mask.ndim < vals.ndim:
-        mask = mask[..., None]
-    vals = jnp.where(mask, vals, ident)
-    flat_idx = (
-        bg.compact_idx
-        + jnp.arange(bg.num_blocks, dtype=jnp.int32)[:, None] * bg.local_budget
-    )
-    tail = vals.shape[2:]
-    partials = segment_reduce(
-        vals.reshape((-1,) + tail), flat_idx.reshape(-1),
-        bg.flat_partial_size, reduce,
-    )
-    partials = partials.reshape((bg.num_blocks, bg.local_budget) + tail)
+    with jax.named_scope("tocab.gather"):
+        vals = blocked_edge_values(bg, flat_edge_vals)
+        ident = jnp.asarray(REDUCE_IDENTITY[reduce], vals.dtype)
+        mask = bg.edge_mask
+        while mask.ndim < vals.ndim:
+            mask = mask[..., None]
+        vals = jnp.where(mask, vals, ident)
+    with jax.named_scope("tocab.partials"):
+        flat_idx = (
+            bg.compact_idx
+            + jnp.arange(bg.num_blocks, dtype=jnp.int32)[:, None] * bg.local_budget
+        )
+        tail = vals.shape[2:]
+        partials = segment_reduce(
+            vals.reshape((-1,) + tail), flat_idx.reshape(-1),
+            bg.flat_partial_size, reduce,
+        )
+        partials = partials.reshape((bg.num_blocks, bg.local_budget) + tail)
     return reduce_partials(bg, partials, reduce)
 
 
@@ -578,8 +566,7 @@ def tocab_edge_reduce(
         chaos.maybe_raise("kernel.tocab_fused")
         from repro.kernels.tocab_fused import fused_edge_reduce
 
-        _record_engine("tocab_edge_reduce_fused", bg.direction,
-                       bg.num_blocks, bg.m)
+        _record_engine("tocab_edge_reduce_fused", bg.direction)
         return fused_edge_reduce(bg, flat_edge_vals, reduce, epilogue)
 
     def _slab():
@@ -596,8 +583,7 @@ def tocab_edge_reduce(
             epilogue)
 
     def _reference():
-        _record_engine("tocab_edge_reduce_reference", bg.direction,
-                       bg.num_blocks, bg.m)
+        _record_engine("tocab_edge_reduce_reference", bg.direction)
         return _slab_epilogue(
             _edge_reduce_uniform(bg, flat_edge_vals, reduce), reduce,
             epilogue)

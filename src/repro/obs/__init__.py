@@ -9,8 +9,8 @@ training, serving) — first-class and uniformly exportable:
 * :mod:`repro.obs.metrics` — labeled counters / gauges / histograms in one
   process-wide :data:`~repro.obs.metrics.registry`.
 * :mod:`repro.obs.trace`  — nested span context managers emitting JSONL,
-  with ``jax.block_until_ready`` attribution and an opt-in
-  ``jax.profiler`` hook.
+  with ``jax.block_until_ready`` attribution; each span is also a
+  ``jax.profiler`` host annotation, on the device trace's clock.
 * :mod:`repro.obs.export` — run fingerprint (jax version, backend, device
   count, git SHA) and schema-versioned BENCH JSON writers.
 * :mod:`repro.obs.report` — ``python -m repro.obs.report BENCH_x.json

@@ -10,12 +10,16 @@ Usage::
                                # in THIS span, not a later data-dependent one
 
     obs.trace.set_sink("trace.jsonl")      # persist events as JSONL
-    with obs.trace.profiler("/tmp/prof"):  # opt-in jax.profiler trace
-        ...
 
 Span events carry ``name, ts, dur_s, blocked_s, depth, parent, attrs`` and
 are buffered in memory (readable via :func:`events`) and appended to the
-JSONL sink when one is configured.  Nesting is tracked per-thread."""
+JSONL sink when one is configured.  Nesting is tracked per-thread.
+
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name:
+while a profiler trace is running it lands on the profiler's host plane, on
+the clock of the device operations, so a device idle gap can be named by
+the program span around it.  With no trace running the annotation only
+checks that none is active."""
 from __future__ import annotations
 
 import contextlib
@@ -27,7 +31,7 @@ from typing import Optional
 from .metrics import registry
 
 __all__ = [
-    "Span", "span", "events", "clear", "set_sink", "profiler",
+    "Span", "span", "events", "clear", "set_sink",
 ]
 
 _TLS = threading.local()
@@ -88,6 +92,8 @@ class Span:
 @contextlib.contextmanager
 def span(name: str, **attrs):
     """Nested span context manager; yields a :class:`Span`."""
+    import jax
+
     sp = Span(name, attrs)
     stack = _stack()
     parent = stack[-1].name if stack else None
@@ -96,7 +102,8 @@ def span(name: str, **attrs):
     sp._t0 = time.perf_counter()
     ts = time.time()
     try:
-        yield sp
+        with jax.profiler.TraceAnnotation(name):
+            yield sp
     finally:
         sp.dur_s = time.perf_counter() - sp._t0
         stack.pop()
@@ -118,16 +125,3 @@ def span(name: str, **attrs):
         registry.histogram(
             "obs.span_seconds", "span wall time by name"
         ).observe(sp.dur_s, name=name)
-
-
-@contextlib.contextmanager
-def profiler(logdir: str):
-    """Opt-in ``jax.profiler`` trace around a region (TensorBoard-readable).
-
-    Separate from spans on purpose: the profiler costs real overhead and
-    disk, so it is never implied by instrumentation — callers reach for it
-    explicitly when a span shows an anomaly worth a device timeline."""
-    import jax
-
-    with jax.profiler.trace(logdir):
-        yield

@@ -218,17 +218,6 @@ def test_spmv_balanced(setup):
         rtol=2e-5, atol=2e-5)
 
 
-def test_timed_tolerates_pytree_returns(setup):
-    """`timed()` must block on engines returning pytrees, not just arrays."""
-    from repro.core.tocab import timed
-    g, dg, bg, _ = setup
-    x = _vals(g.n)
-    out = timed(
-        lambda b, v: {"rank": tocab_pull(b, v), "iters": 3, "note": "ok"},
-        bg, x, engine="pytree_engine")
-    assert out["iters"] == 3 and out["rank"].shape == (g.n,)
-
-
 def test_obs_bin_counters(setup):
     from repro.obs.metrics import registry
     g, dg, bg, _ = setup

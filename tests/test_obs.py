@@ -156,31 +156,127 @@ def test_engines_populate_registry():
 
     names = registry.names()
     for want in (
-        "tocab.engine_traces", "tocab.blocks", "tocab.edges",
+        "tocab.engine_traces",
         "traversal.frontier_size", "traversal.frontier_edges",
         "traversal.iterations",
         "cache.miss_rate", "cache.dram_per_edge", "cache.simulations",
     ):
         assert want in names, f"missing metric {want}"
-    # trace-time static facts for the TOCAB engine
-    assert registry.gauge("tocab.blocks").value(
-        engine="tocab_pull") == bg.num_blocks
     # BFS ran some iterations and the debug.callback delivered them
     total = bfs_iters() - before
     assert total >= int(levels)
     assert total == int(n_push) + int(n_pull)
 
 
-def test_tocab_timed_records_throughput():
+
+# ------------------- spans on the profiler's clock ------------------- #
+def _host_events(trace_dir) -> list:
+    """``(name, start_ns, end_ns)`` of every host-plane event of the one
+    profiler trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    [path] = list(trace_dir.rglob("*.xplane.pb"))
+    return [(e.name, e.start_ns, e.end_ns)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host")
+            for line in plane.lines for e in line.events]
+
+
+def test_span_lands_on_profiler_host_plane(tmp_path):
+    import jax
+
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.span("obs.test_outer", rows=3):
+            with trace.span("obs.test_inner"):
+                jnp.ones((4,)).block_until_ready()
+    host = {n: (s, e) for n, s, e in _host_events(tmp_path)}
+    assert {"obs.test_outer", "obs.test_inner"} <= host.keys()
+    outer, inner = host["obs.test_outer"], host["obs.test_inner"]
+    assert outer[0] <= inner[0] <= inner[1] <= outer[1]
+    # the in-memory event is still recorded, as without a profiler
+    assert [e["name"] for e in trace.events()] == [
+        "obs.test_inner", "obs.test_outer"]
+    assert trace.events()[1]["attrs"] == {"rows": 3}
+
+
+SETUP_STEPS = ("build_blocked.sort", "build_blocked.fill",
+               "build_blocked.place")
+
+
+@pytest.mark.parametrize("direction", ["pull", "push"])
+def test_build_blocked_spans_its_steps(tmp_path, direction):
+    import jax
     from repro.core import graph as G
     from repro.core.partition import build_blocked
-    from repro.core import tocab
 
-    rng = np.random.default_rng(1)
+    rng = np.random.default_rng(3)
+    g = G.from_edges(64, rng.integers(0, 64, 300), rng.integers(0, 64, 300))
+    with jax.profiler.trace(str(tmp_path)):
+        build_blocked(g, block_size=16, direction=direction)
+    evts = trace.events()
+    assert [e["name"] for e in evts] == [*SETUP_STEPS, "build_blocked"]
+    assert evts[-1]["attrs"] == {"direction": direction, "n": g.n, "m": g.m}
+    assert all(e["parent"] == "build_blocked" for e in evts[:-1])
+    # on the profiler's clock every step lies inside the call
+    host = {n: (s, e) for n, s, e in _host_events(tmp_path)
+            if n.startswith("build_blocked")}
+    lo, hi = host["build_blocked"]
+    steps = [host[name] for name in SETUP_STEPS]
+    assert all(lo <= s <= e <= hi for s, e in steps)
+    assert all(a[1] <= b[0] for a, b in zip(steps, steps[1:]))
+
+
+def test_device_graph_place_span():
+    from repro.core import graph as G
+    from repro.core.graph import DeviceGraph
+
+    rng = np.random.default_rng(4)
     g = G.from_edges(32, rng.integers(0, 32, 100), rng.integers(0, 32, 100))
-    bg = build_blocked(g, block_size=8, direction="pull")
-    out = tocab.timed(tocab.tocab_pull, bg, jnp.ones((g.n,), jnp.float32))
-    assert out.shape == (g.n,)
-    st = registry.histogram("tocab.call_seconds").stats(engine="tocab_pull")
-    assert st is not None and st["count"] >= 1
-    assert registry.gauge("tocab.edges_per_s").value(engine="tocab_pull") > 0
+    dg = DeviceGraph.from_host(g)
+    assert dg.m == g.m
+    [ev] = trace.events()
+    assert ev["name"] == "device_graph.place" and ev["parent"] is None
+
+
+# -------------------- named scopes in compiled HLO -------------------- #
+SLAB_PHASES = {"tocab.gather", "tocab.partials", "tocab.reduce"}
+
+
+def _scope_names(compiled_text: str) -> set:
+    import re
+
+    return {part for path in re.findall(r'op_name="([^"]*)"', compiled_text)
+            for part in re.split(r"[/;]", path)}
+
+
+def _compiled(entry: str) -> str:
+    import jax
+    from repro.core import graph as G
+    from repro.core import tocab
+    from repro.core.graph import DeviceGraph
+    from repro.core.pagerank import _pagerank_jit
+    from repro.core.partition import build_blocked
+
+    rng = np.random.default_rng(5)
+    g = G.from_edges(64, rng.integers(0, 64, 400), rng.integers(0, 64, 400))
+    x = jnp.ones((g.n,), jnp.float32)
+    if entry == "pagerank":
+        fn, args = _pagerank_jit, (
+            DeviceGraph.from_host(g), build_blocked(g, block_size=16),
+            "gc-pull", 0.85, 1e-4, 20, True, "uniform", "slab", False)
+    elif entry == "push":
+        fn, args = jax.jit(tocab.tocab_push), (
+            build_blocked(g, block_size=16, direction="push"), x)
+    else:
+        fn, args = jax.jit(tocab.tocab_edge_reduce), (
+            build_blocked(g, block_size=16), jnp.ones((g.m,), jnp.float32))
+    return fn.lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("entry,names", [
+    ("pagerank", SLAB_PHASES | {"pagerank.step"}),
+    ("push", SLAB_PHASES),
+    ("edge_reduce", SLAB_PHASES),
+])
+def test_compiled_slab_phases_carry_their_scopes(entry, names):
+    assert names <= _scope_names(_compiled(entry))
