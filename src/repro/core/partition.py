@@ -60,6 +60,14 @@ class BlockedGraph:
                    side → partial_sums)     side → block_contrib)
     id_map         local dst → global dst   local src → global src
     =============  =======================  =======================
+
+    Layout contract: ``compact_idx`` is non-decreasing along each block
+    row, padding included — the edges of a block are sorted by their
+    compacted side, and a padded slot holds the block's last real local id,
+    ``max(n_local[b] − 1, 0)``.  Every id lies below ``local_budget``, so
+    the flat keys ``compact_idx[b] + b·local_budget`` never decrease over
+    the whole slab, which lets phase 2 declare its scatter sorted.  Padded
+    slots are masked by ``edge_mask`` wherever their id is read.
     """
 
     # --- static metadata (aux data, not traced) ---
@@ -215,7 +223,11 @@ def build_blocked(
             # --- fill padded slabs ---
             shape_e = (num_blocks, edge_budget)
             window_idx = np.zeros(shape_e, dtype=np.int32)
-            compact_idx = np.zeros(shape_e, dtype=np.int32)
+            # padding repeats the block's last local id: keeps each row
+            # sorted (the layout contract in BlockedGraph's docstring)
+            compact_idx = np.repeat(
+                np.maximum(n_local - 1, 0).astype(np.int32)[:, None],
+                edge_budget, axis=1)
             edge_mask = np.zeros(shape_e, dtype=bool)
             edge_perm = np.full(shape_e, g.m, dtype=np.int32)
             edge_vals = (None if vals is None

@@ -194,6 +194,16 @@ def tocab_pull_partials(
         src_global = bg.window_idx + bg.window_lo()[:, None]
         msgs = _edge_messages(values, src_global, bg.edge_vals, bg.edge_mask,
                               reduce, combine)
+    return _block_partials(bg, msgs, reduce)
+
+
+def _block_partials(bg: BlockedGraph, msgs: jnp.ndarray, reduce: str):
+    """Phase 2's segment reduce of per-edge messages ``(num_blocks,
+    edge_budget, *tail)`` into the per-block compacted slab.  Its flat keys
+    ``compact_idx + b·local_budget`` never decrease over the slab (the
+    layout contract of :class:`BlockedGraph`), so the scatter is declared
+    sorted: the TPU compiler then skips the presort it runs before a large
+    scatter."""
     with jax.named_scope("tocab.partials"):
         flat_idx = (
             bg.compact_idx
@@ -205,6 +215,7 @@ def tocab_pull_partials(
             flat_idx.reshape(-1),
             bg.flat_partial_size,
             reduce,
+            sorted_ids=True,
         )
         return partials.reshape((bg.num_blocks, bg.local_budget) + tail)
 
@@ -524,18 +535,7 @@ def _edge_reduce_uniform(bg: BlockedGraph, flat_edge_vals, reduce: str):
         while mask.ndim < vals.ndim:
             mask = mask[..., None]
         vals = jnp.where(mask, vals, ident)
-    with jax.named_scope("tocab.partials"):
-        flat_idx = (
-            bg.compact_idx
-            + jnp.arange(bg.num_blocks, dtype=jnp.int32)[:, None] * bg.local_budget
-        )
-        tail = vals.shape[2:]
-        partials = segment_reduce(
-            vals.reshape((-1,) + tail), flat_idx.reshape(-1),
-            bg.flat_partial_size, reduce,
-        )
-        partials = partials.reshape((bg.num_blocks, bg.local_budget) + tail)
-    return reduce_partials(bg, partials, reduce)
+    return reduce_partials(bg, _block_partials(bg, vals, reduce), reduce)
 
 
 def tocab_edge_reduce(

@@ -78,3 +78,27 @@ def test_choose_block_size_vmem_budget():
     bs = choose_block_size(10**7, fast_mem_bytes=4 * 1024 * 1024)
     assert bs * 4 <= 4 * 1024 * 1024
     assert bs % 128 == 0
+
+
+@pytest.mark.parametrize("direction", ["pull", "push"])
+@pytest.mark.parametrize("graph", ["edge_free_block", "rmat"])
+def test_compact_idx_sorted_with_padding(request, graph, direction):
+    """The layout contract phase 2 declares to the compiler: each padded
+    slot repeats its block's last local id, so the flat scatter keys
+    ``compact_idx + b·local_budget`` never decrease over the whole slab."""
+    if graph == "rmat":
+        g, block_size = rmat_graph(scale=9, edge_factor=8, seed=3), 64
+    else:
+        g, block_size = request.getfixturevalue("edge_free_block_graph")
+    bg = build_blocked(g, block_size=block_size, direction=direction)
+    cidx = np.asarray(bg.compact_idx)
+    mask = np.asarray(bg.edge_mask)
+    nloc = np.asarray(bg.n_local)
+    if graph == "edge_free_block":
+        assert not mask[2].any() and nloc[2] == 0
+        assert (nloc == bg.local_budget).sum() == 2
+    assert (~mask).any()  # the slab has padding to check
+    for b in range(bg.num_blocks):
+        assert (cidx[b][~mask[b]] == max(nloc[b] - 1, 0)).all()
+    flat = cidx + np.arange(bg.num_blocks)[:, None] * bg.local_budget
+    assert (np.diff(flat.reshape(-1)) >= 0).all()

@@ -57,6 +57,9 @@ def test_partition_conservation(g, block_size):
     for b in range(bg.num_blocks):
         if mask[b].any():
             assert cidx[b][mask[b]].max() < nloc[b]
+    # the flat phase-2 keys, padding included, never decrease
+    flat = cidx + np.arange(bg.num_blocks)[:, None] * bg.local_budget
+    assert (np.diff(flat.reshape(-1)) >= 0).all()
 
 
 @given(random_graph())

@@ -1,4 +1,7 @@
 """Engine equivalence: TOCAB == baseline across semirings/shapes (§7 item 3)."""
+import re
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -48,6 +51,44 @@ def test_minmax_semiring(setup, reduce):
     finite = np.isfinite(ref)
     assert (np.isfinite(out) == finite).all()
     np.testing.assert_allclose(out[finite], ref[finite], rtol=1e-6)
+
+
+@pytest.mark.parametrize("reduce", ["sum", "min", "max"])
+def test_pull_with_edge_free_block(edge_free_block_graph, reduce):
+    """A block without edges and blocks filled to ``local_budget``: the
+    corner cases of the padding that keeps phase 2's keys sorted."""
+    g, block_size = edge_free_block_graph
+    dg = DeviceGraph.from_host(g)
+    bg = build_blocked(g, block_size=block_size, direction="pull")
+    x = _vals(g.n)
+    ref = np.asarray(baseline_pull(dg, x, reduce=reduce))
+    out = np.asarray(tocab_pull(bg, x, reduce=reduce))
+    finite = np.isfinite(ref)
+    assert (np.isfinite(out) == finite).all()
+    np.testing.assert_allclose(out[finite], ref[finite], rtol=1e-6)
+
+
+def _scatters_sorted(text: str) -> dict:
+    """``{output length: indices_are_sorted}`` of each scatter in lowered
+    StableHLO text."""
+    found = re.findall(
+        r'"stablehlo\.scatter".*?indices_are_sorted = (\w+).*?'
+        r'\}\) : \(tensor<(\d+)', text, flags=re.S)
+    return {int(size): flag == "true" for flag, size in found}
+
+
+@pytest.mark.parametrize("engine", ["tocab_pull", "tocab_edge_reduce"])
+def test_phase2_scatter_declared_sorted(setup, engine):
+    """Phase 2's scatter into the flat partial slab carries the sorted
+    hint (the TPU compiler then drops its presort); phase 3's, keyed by
+    ``id_map``, does not."""
+    g, _, bg, _ = setup
+    if engine == "tocab_pull":
+        lowered = jax.jit(tocab_pull).lower(bg, _vals(g.n))
+    else:
+        lowered = jax.jit(tocab_edge_reduce).lower(bg, _vals(g.m))
+    sorted_by_size = _scatters_sorted(lowered.as_text())
+    assert sorted_by_size == {bg.flat_partial_size: True, g.n + 1: False}
 
 
 def test_combine_minplus(setup):

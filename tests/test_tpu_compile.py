@@ -4,8 +4,9 @@ Interpret mode cannot see what Mosaic refuses — block shapes off the
 (8, 128) tiling, vector-indexed gathers, VMEM overflow — so each kernel is
 lowered and compiled by the installed TPU compiler for a *described*
 ``v5e:2x2`` topology at the benchmark suite's ``rmat14`` shapes (the
-``benchmarks.common`` suite graph, ``BLOCK_SIZE`` 2048).  Nothing runs and
-no chip is needed.  The topology is described inside a fixture: only the
+``benchmarks.common`` suite graph, ``BLOCK_SIZE`` 2048).  The slab pull is
+compiled the same way at a GAP kron scale-21 layout, to see what the
+compiler adds around its scatters.  Nothing runs and no chip is needed.  The topology is described inside a fixture: only the
 worker that runs these tests loads the TPU compiler.
 """
 import os
@@ -15,6 +16,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import build_blocked, rmat_graph
+from repro.core.partition import BlockedGraph
+from repro.core.tocab import _tocab_pull_jit
 from repro.kernels.common import LANE
 from repro.kernels.tocab_fused.kernel import (
     fused_pull_pallas, fused_push_pallas)
@@ -145,3 +148,27 @@ def test_tocab_spmm_chunk_too_big_for_smem_raises(one_chip):
             _spec(one_chip, (nb, eb), i32), _spec(one_chip, (nb, eb)),
             block_size=bs, local_budget=lb, chunk=eb, mode="scatter",
             interpret=False)
+
+
+def test_slab_pull_phase2_has_no_presort(one_chip):
+    """Above a size threshold (between 38.6M and 89.2M updates) the TPU
+    compiler sorts a scatter's keys before it scatters, unless they are
+    declared sorted.  The slab pull at a GAP kron scale-21 layout (256
+    blocks of 348,544 slots, 89.2M phase-2 updates) compiles with no sort:
+    phase 2 declares its keys sorted, and phase 3 (38.6M partials into
+    2.1M vertices) lies below the threshold.  Abstract shapes: nothing is
+    allocated."""
+    nb, eb, lb, bs = 256, 348544, 150784, 8192
+    n = nb * bs
+    i32 = jnp.int32
+    slab = _spec(one_chip, (nb, eb), i32)
+    per_block = _spec(one_chip, (nb,), i32)
+    bg = BlockedGraph(
+        n=n, m=63538550, direction="pull", block_size=bs, num_blocks=nb,
+        edge_budget=eb, local_budget=lb, window_idx=slab, compact_idx=slab,
+        edge_mask=_spec(one_chip, (nb, eb), jnp.bool_),
+        id_map=_spec(one_chip, (nb, lb), i32), n_local=per_block,
+        n_edges=per_block, edge_perm=slab, n_window=per_block)
+    text = _tocab_pull_jit.lower(bg, _spec(one_chip, (n,))).compile().as_text()
+    assert text.count("scatter(") == 2
+    assert "sort(" not in text
