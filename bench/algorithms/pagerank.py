@@ -82,6 +82,13 @@ def reference(g, params: dict, at_steps) -> dict:
             "ranks": snaps, "deltas": deltas}
 
 
+def describe(ref: dict) -> dict:
+    """The reference's step count and its L1 change at every step, for the
+    check log."""
+    return {"reference_steps": ref["steps"],
+            "reference_deltas": ",".join(f"{d:.6e}" for d in ref["deltas"])}
+
+
 def compare(outs: list, ref: dict) -> dict:
     """The numbers that decide ``correct``, worst over the solves kept:
     ``rank_l1_gap``, the L1 distance of a solve's rank from the

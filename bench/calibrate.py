@@ -7,9 +7,10 @@
 For each of ``--seeds`` it runs the cell as ``run.py`` does, with a window
 of one solve, and records each number compared; for each of
 ``--control-seeds`` it does the same with the algorithm's lower-precision
-control in the program's place.  The limit of a number lies above the
-largest program reading and below the smallest control reading.  The
-benchmark's own runs never run this.  Exits non-zero without a TPU.
+control in the program's place (refused for an algorithm that has none).
+The limit of a number lies above the largest program reading and below the
+smallest control reading.  The benchmark's own runs never run this.  Exits
+non-zero without a TPU.
 """
 from __future__ import annotations
 
@@ -35,6 +36,9 @@ def main(argv=None) -> int:
     control_seeds = [int(s) for s in args.control_seeds.split(",") if s]
 
     cell, peaks = bench_run.open_cell(args.workload)
+    if control_seeds and not hasattr(cell.algorithm, "control"):
+        sys.exit(f"calibrate: {cell.name}'s algorithm has no control; "
+                 "run it without --control-seeds")
     import jax
 
     rows = []

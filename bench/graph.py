@@ -9,6 +9,13 @@ every block holds the same vertices' arcs and as many distinct
 neighbours, so every seed gives the program layouts of the same shapes and
 the same amount of work (and, compiled once, the same programs), while the
 ids, the adjacency and the answers differ from seed to seed.
+
+The relabelling is kept on the graph (``HostGraph.run_id``: drawn id ->
+run id).  An algorithm whose solves take an input of their own, such as a
+BFS source, chooses it in the drawn graph and maps it with ``run_id``:
+solve ``i`` then starts from the same drawn vertex under every seed and
+does isomorphic work, where a vertex id chosen in the relabelled graph
+would be another vertex, with another depth profile, for every seed.
 """
 from __future__ import annotations
 
@@ -26,6 +33,8 @@ class HostGraph:
     n: int
     rowptr: np.ndarray  # int64[n + 1]
     colidx: np.ndarray  # int32[arcs]
+    #: drawn id -> id in this graph, where the graph is a relabelling
+    run_id: np.ndarray | None = None  # int32[n]
 
     @property
     def arcs(self) -> int:
@@ -63,7 +72,8 @@ def simple_undirected(n: int, src: np.ndarray, dst: np.ndarray) -> HostGraph:
 def relabel_within_blocks(g: HostGraph, seed: int, block: int) -> HostGraph:
     """The graph under a random relabelling that keeps every vertex in its
     block of ``block`` ids: row ``w`` of the result is row ``old[w]`` of
-    ``g`` with every neighbour relabelled."""
+    ``g`` with every neighbour relabelled, and its ``run_id`` maps each id
+    of ``g`` to its id in the result."""
     rng = np.random.default_rng(seed_words(seed))
     ids = np.arange(g.n, dtype=np.int64)
     old = np.lexsort((rng.random(g.n), ids // block))  # new id -> old id
@@ -74,7 +84,7 @@ def relabel_within_blocks(g: HostGraph, seed: int, block: int) -> HostGraph:
     np.cumsum(deg, out=rowptr[1:])
     take = np.repeat(g.rowptr[:-1][old] - rowptr[:-1], deg)
     take += np.arange(g.arcs, dtype=np.int64)
-    return HostGraph(g.n, rowptr, new_id[g.colidx[take]])
+    return HostGraph(g.n, rowptr, new_id[g.colidx[take]], run_id=new_id)
 
 
 def make_graph(config: dict, seed: int, block: int) -> HostGraph:

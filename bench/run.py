@@ -7,9 +7,42 @@
 Run from the root of a checkout that holds the program (``src/repro``).
 A cell is a configuration (``bench/configs/<config>.json``: the graph)
 under a traffic mix (``bench/traffic/<traffic>.json``: which algorithm,
-with which parameters and limits); the algorithm's entry call, reference,
-control and least bytes are in ``bench/algorithms/<algorithm>.py``, and
-each metric is read by ``bench/metrics/<metric>.py``.
+with which parameters and limits); the algorithm's entry call, reference
+and least bytes are in ``bench/algorithms/<algorithm>.py``, and each metric
+is read by ``bench/metrics/<metric>.py``.  A cell of another algorithm
+needs new files alone: its algorithm module, its traffic mix, its readers
+and its entries in ``BENCHMARK.json``.
+
+An algorithm module defines:
+
+- ``LAYOUTS``: the directions of the blocked layouts its entry reads;
+- ``solve(core, dg, layouts, params[, x])``: one timed call, on the
+  device; ``steps(out)`` and ``answer(out)`` read its loop steps and its
+  answer (to the host) from what it returns;
+- ``warmup_inputs(dg, layouts)``: ``(dg, layouts[, x])`` of the timed
+  call's shapes, on which a solve is short;
+- ``reference(hg, params, at_steps[, xs])``: the plain reference on the
+  host graph, for the kept solves' step counts;
+- ``compare(answers, ref[, xs])``: ``{check: number}`` over the kept
+  solves' ``(answer, steps)``; ``correct`` holds where each number is at
+  most the traffic's ``limits`` of that name, and the keys are the limits';
+- ``least_bytes(hg, steps[, x])``: the bytes a solve must move;
+- optionally ``solve_inputs(hg, params)``: a function of the solve's index
+  ``i`` (0, 1, ... over the window) that gives that solve's own input
+  ``x``, such as a source, chosen in the drawn graph and mapped through
+  ``hg.run_id`` (``bench/graph.py``), so that solve ``i`` does isomorphic
+  work under every seed.  Only where it is defined do the calls above take
+  the bracketed arguments: ``x`` is the solve's input and ``xs`` the kept
+  solves' inputs, in the order of ``at_steps`` and ``answers``;
+- optionally ``describe(ref)``: fields of the reference for the check log;
+- optionally ``control_inputs(hg)`` and ``control(inputs, params[, x])``:
+  the reference in a lower precision in the program's place, which
+  ``bench/calibrate.py`` runs.
+
+A metric module defines ``read(run)``, which returns the metric's value or
+None where the run holds nothing to read.  ``run.scopes`` holds, in a
+traced run, the device seconds of every program scope (``jax.named_scope``)
+in the window by name (``bench/scopes.py``), and is None untraced.
 
 Set-up (counted in ``setup_s`` from the start of the process): generate
 the graph from the seed, build the blocked layouts the algorithm reads,
@@ -83,30 +116,31 @@ class Cell:
     per_layer: list
 
 
-def resolve_cell(name: str, bench: dict | None = None) -> Cell:
+def resolve_cell(name: str, root: str = ROOT) -> Cell:
     """Find a cell's configuration, traffic mix, algorithm and metric
-    readers by the names in ``BENCHMARK.json``."""
-    if bench is None:
-        bench = read_json(os.path.join(ROOT, "BENCHMARK.json"))
+    readers by the names in ``BENCHMARK.json``, in the checkout at
+    ``root``."""
+    bench = read_json(os.path.join(root, "BENCHMARK.json"))
+    here = os.path.join(root, "bench")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise SystemExit(f"bench: no workload {name!r} in BENCHMARK.json; "
                          f"there are {sorted(cells)}")
     w = cells[name]
     config = {c["name"]: c for c in bench["configs"]}[w["config"]]
-    traffic = read_json(os.path.join(BENCH, "traffic", w["traffic"] + ".json"))
+    traffic = read_json(os.path.join(here, "traffic", w["traffic"] + ".json"))
 
     def readers(metrics):
         return [(m["name"], m["unit"],
-                 load_module(os.path.join(BENCH, "metrics", m["name"] + ".py")))
+                 load_module(os.path.join(here, "metrics", m["name"] + ".py")))
                 for m in metrics if name in m.get("workloads", [name])]
 
     return Cell(
         name=name, chips=int(w["chips"]),
-        config=read_json(os.path.join(ROOT, config["file"])),
+        config=read_json(os.path.join(root, config["file"])),
         traffic=traffic,
         algorithm=load_module(os.path.join(
-            BENCH, "algorithms", traffic["algorithm"] + ".py")),
+            here, "algorithms", traffic["algorithm"] + ".py")),
         end_to_end=readers(bench["end_to_end"]),
         per_layer=readers(bench["per_layer"]))
 
@@ -160,6 +194,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     from repro import core
     from repro.configs.graphcage import GraphCageCfg
 
+    from bench import scopes
     from bench import trace as trace_mod
     from bench.graph import make_graph, seed_words
 
@@ -174,14 +209,22 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     hg = make_graph(cell.config, seed, block_size)
     phases["generate_s"] = time.perf_counter() - t
     log("setup", generate_s=phases["generate_s"], n=hg.n, arcs=hg.arcs)
+    draw = (algo.solve_inputs(hg, params)
+            if hasattr(algo, "solve_inputs") else None)
+
+    def input_of(i: int) -> tuple:
+        """Solve ``i``'s own input as the trailing arguments of the
+        algorithm's calls: none where its solves take none."""
+        return () if draw is None else (draw(i),)
 
     if control:
         inputs = jax.block_until_ready(algo.control_inputs(hg))
 
-        def solve():
-            return algo.control(inputs, params)
+        def solve(*x):
+            return algo.control(inputs, params, *x)
 
-        warmup = solve
+        def warmup():
+            return solve(*input_of(0))
     else:
         g = core.Graph(n=hg.n, rowptr=hg.rowptr, colidx=hg.colidx)
         t = time.perf_counter()
@@ -202,13 +245,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                 edge_budget=b.edge_budget, local_budget=b.local_budget,
                 padding=b.padding_fraction())
 
-        def solve():
-            return algo.solve(core, dg, layouts, params)
+        def solve(*x):
+            return algo.solve(core, dg, layouts, params, *x)
 
         warm = algo.warmup_inputs(dg, layouts)
 
         def warmup():
-            return algo.solve(core, *warm, params)
+            return algo.solve(core, warm[0], warm[1], params, *warm[2:])
 
     t = time.perf_counter()
     with jax.profiler.TraceAnnotation("bench.warmup"):
@@ -222,36 +265,45 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     # keep the first solve, the last, and one drawn from the seed
     sample = int(np.random.default_rng(seed_words(seed)).integers(1, 8))
-    kept, steps, failed = {}, [], 0
+    kept, steps, xs, failed, dispatch_s = {}, [], [], 0, 0.0
     compiles_before = compiles.count
     tmp = tempfile.TemporaryDirectory(prefix="bench-trace-") if trace else None
     if trace:
         jax.profiler.start_trace(tmp.name)
-    t0 = time.perf_counter()
+    t0, cpu0 = time.perf_counter(), time.process_time()
     with jax.profiler.TraceAnnotation("bench.window"):
         while True:
+            x = input_of(len(steps))
             with jax.profiler.TraceAnnotation("bench.solve"):
-                out = jax.block_until_ready(solve())
+                t = time.perf_counter()
+                out = solve(*x)
+                dispatch_s += time.perf_counter() - t
+                out = jax.block_until_ready(out)
             if len(steps) in (0, sample):
                 kept[len(steps)] = out
             steps.append(algo.steps(out))
+            xs.append(x)
             failed += fallback_series() > fallbacks_before
             t1 = time.perf_counter()
             if t1 - t0 >= seconds:
                 break
-    window_s = t1 - t0
+    window_s, cpu_s = t1 - t0, time.process_time() - cpu0
     if trace:
         jax.profiler.stop_trace()
     compiles_in_window = compiles.count - compiles_before
     peak = memory_peak(device)
+    # dispatch_s: host seconds until the solves' calls returned, before
+    # their wait; cpu_s: the process's CPU seconds in the window
     log("window", solves=len(steps), window_s=window_s,
         steps=",".join(map(str, sorted(set(steps)))), failed=failed,
-        compiles_in_window=compiles_in_window, memory_peak_bytes=peak)
+        compiles_in_window=compiles_in_window, memory_peak_bytes=peak,
+        dispatch_s=dispatch_s, cpu_s=cpu_s)
 
     kept[len(steps) - 1] = out
     # answers to the host, then free the program's state before the
     # reference runs
     answers = [(algo.answer(o), algo.steps(o)) for o in kept.values()]
+    kept_xs = () if draw is None else ([xs[i][0] for i in kept],)
     del out, kept, solve, warmup
     if control:
         del inputs
@@ -259,26 +311,33 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         del dg, layouts
     gc.collect()
 
-    summary = None
+    summary = scope_s = None
     if trace:
-        summary = trace_mod.reduce(trace_mod.load(tmp.name), "bench.window")
+        events = trace_mod.load(tmp.name)
+        summary = trace_mod.reduce(events, "bench.window")
+        scope_s = scopes.by_scope(scopes.load(tmp.name),
+                                  trace_mod.span(events, "bench.window"))
         tmp.cleanup()
+        del events
 
     t = time.perf_counter()
-    ref = algo.reference(hg, params, [k for _, k in answers])
-    numbers = algo.compare(answers, ref)
+    ref = algo.reference(hg, params, [k for _, k in answers], *kept_xs)
+    numbers = algo.compare(answers, ref, *kept_xs)
     limits = cell.traffic["limits"]
+    if set(numbers) != set(limits):
+        raise RuntimeError(f"bench: {cell.name} compares {sorted(numbers)}, "
+                           f"its traffic limits {sorted(limits)}")
     correct = all(v <= limits[k] for k, v in numbers.items())
-    log("check", reference_s=time.perf_counter() - t,
-        reference_steps=ref["steps"],
-        reference_deltas=",".join(f"{d:.6e}" for d in ref["deltas"]),
+    described = algo.describe(ref) if hasattr(algo, "describe") else {}
+    log("check", reference_s=time.perf_counter() - t, **described,
         correct=correct)
 
     run = types.SimpleNamespace(
         setup_s=setup_s, phases=phases, window_s=window_s, solves=len(steps),
         steps=steps, memory_peak_bytes=peak, layouts=layout_facts,
-        least_bytes=sum(algo.least_bytes(hg, k) for k in steps),
-        trace=summary, peaks=peaks)
+        least_bytes=sum(algo.least_bytes(hg, k, *x)
+                        for k, x in zip(steps, xs)),
+        trace=summary, scopes=scope_s, peaks=peaks)
     metrics = {}
     for name, unit, reader in (cell.per_layer if trace else cell.end_to_end):
         value = reader.read(run)

@@ -143,6 +143,23 @@ def seconds(ops: list, window: tuple, names) -> dict:
     return out
 
 
+def by_scope(ops: list, window: tuple) -> dict:
+    """Device seconds within ``window`` of every scope name in ``ops``'
+    paths, summed over chips: what a per-layer reader looks a scope up
+    in."""
+    names = set().union(*(components(o[1]) for o in ops)) - {""}
+    return seconds(ops, window, names)
+
+
+def per_step(run, name: str) -> float | None:
+    """Device seconds of scope ``name`` in a run's traced window over the
+    window's loop steps; None untraced, or where no operation carries the
+    scope."""
+    if run.scopes is None or name not in run.scopes or not sum(run.steps):
+        return None
+    return run.scopes[name] / sum(run.steps)
+
+
 def host_seconds(*names) -> float | None:
     """Host seconds of the program's finished ``repro.obs`` spans with one
     of ``names``, summed; None where the program recorded none."""

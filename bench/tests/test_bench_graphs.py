@@ -2,7 +2,8 @@
 import numpy as np
 import pytest
 
-from bench.graph import make_graph
+from bench.generators import kron
+from bench.graph import make_graph, simple_undirected
 
 SCALE = 10
 BLOCK = 128
@@ -76,3 +77,20 @@ def test_kron_is_skewed_and_urand_is_not():
 def test_directed_config_is_refused():
     with pytest.raises(ValueError, match="undirected"):
         make_graph(dict(config("urand"), undirected=False), seed=0, block=BLOCK)
+
+
+
+@pytest.mark.parametrize("seed", [0, 2**40 + 3])
+def test_run_id_maps_the_drawn_graph_onto_the_run(seed):
+    """``run_id`` takes each drawn vertex to its id in the run's graph,
+    within its block, and every drawn arc to an arc of the run."""
+    cfg = config("kron")
+    run = make_graph(cfg, seed=seed, block=BLOCK)
+    drawn = simple_undirected(run.n, *kron.draw(cfg, cfg["graph_seed"]))
+    ids = run.run_id
+    assert drawn.run_id is None and ids.dtype == np.int32
+    assert np.array_equal(np.sort(ids), np.arange(run.n))
+    assert np.array_equal(ids // BLOCK, np.arange(run.n) // BLOCK)
+    src = np.repeat(np.arange(drawn.n), drawn.degree)
+    mapped = ids[src].astype(np.int64) * run.n + ids[drawn.colidx]
+    assert np.array_equal(np.sort(mapped), np.sort(arcs_of(run)))

@@ -25,9 +25,14 @@ PEAKS = {"hbm_bytes_per_s": 819e9}
 def test_cell_resolves_to_its_files(name):
     cell = bench_run.resolve_cell(name)
     assert cell.config["name"] == name.split(".")[0]
-    for key in ("solve", "reference", "compare", "least_bytes", "control"):
+    for key in ("solve", "warmup_inputs", "steps", "answer", "reference",
+                "compare", "least_bytes"):
         assert callable(getattr(cell.algorithm, key))
-    assert set(cell.traffic["limits"]) >= {"rank_l1_gap", "steps_gap"}
+    # the cell's own checks: what compare reads on a small run is exactly
+    # its traffic's limits
+    assert cell.traffic["limits"]
+    assert set(run_small(small_cell(name))["checks"]) == set(
+        cell.traffic["limits"])
     e2e = {m["name"] for m in BENCHMARK["end_to_end"]}
     assert {n for n, _, _ in cell.end_to_end} == e2e
     assert "setup_s" in e2e
@@ -93,7 +98,8 @@ def run_small(cell, **kw):
 
 @pytest.mark.parametrize("name", CELLS)
 def test_program_is_correct_at_small_size(name, capsys):
-    result = run_small(small_cell(name))
+    cell = small_cell(name)
+    result = run_small(cell)
     assert result["correct"], result["checks"]
     assert result["attempted"] >= 1 and result["failed"] == 0
     assert list(result)[-1] == "checks"
@@ -101,7 +107,9 @@ def test_program_is_correct_at_small_size(name, capsys):
         assert c["value"] <= c["limit"]
     assert set(result["metrics"]) == {"result_s", "setup_s"}  # no HBM on CPU
     json.dumps(result, allow_nan=False)
-    assert "check rank_l1_gap=" in capsys.readouterr().err
+    err = capsys.readouterr().err
+    for key in cell.traffic["limits"]:
+        assert f"check {key}=" in err
 
 
 def test_control_is_not_correct():
@@ -173,7 +181,8 @@ def test_traced_run_reports_per_layer_metrics():
     assert result["correct"]
     names = {n for n, _, _ in cell.per_layer}
     assert set(result["metrics"]) == names - {
-        "scatter_busy_share", "device_idle_share", "pagerank_roofline"}
+        "scatter_busy_share", "device_idle_share", "pagerank_roofline",
+        "slab_gather_s", "slab_partials_s", "slab_reduce_s"}
     assert result["device"]["busy_s"] == 0
     assert result["device"]["window_s"] > 0
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}

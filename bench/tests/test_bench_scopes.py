@@ -1,9 +1,10 @@
 """Device time by program scope and host time by program span: the
 ``.xplane.pb`` reader on a hand-encoded trace, the reduction on a trace
-recorded on a TPU v5e and on synthetic cases, and the readers of the
-set-up spans."""
+recorded on a TPU v5e and on synthetic cases, the readers of the
+set-up spans, and the readers of the slab phases' scopes."""
 import json
 import os
+import types
 
 import pytest
 
@@ -172,3 +173,45 @@ def test_setup_reader_sums_its_spans(name, monkeypatch):
     n = len(READS[name]) * 2
     assert _reader(name).read(None) == pytest.approx(
         0.5 * n * (n + 1) / 2)
+
+
+# --------------------- slab-phase readers, by scope --------------------- #
+SLAB = {"slab_gather_s": "tocab.gather", "slab_partials_s": "tocab.partials",
+        "slab_reduce_s": "tocab.reduce"}
+STEP = "jit(_pagerank_jit)/while/body/pagerank.step/jit(_tocab_pull_jit)"
+SYNTHETIC = [
+    (0, "", 0, 1000),  # the loop that encloses the rest: not a leaf
+    (0, STEP + "/tocab.gather/jit(_take)/select_n:", 0, 300),
+    (0, STEP + "/tocab.partials/scatter-add:", 300, 200),
+    (0, STEP + "/tocab.partials/add;" + STEP + "/tocab.reduce/reshape:",
+     500, 100),  # one fusion of two phases counts for both
+    (0, STEP + "/tocab.reduce/scatter-add:", 600, 350),
+    (0, STEP + "/sub:", 950, 50),
+    (0, STEP + "/tocab.gather/take:", 1000, 500),  # after the window
+]
+
+
+def test_by_scope_keeps_every_name_in_the_window():
+    got = scopes.by_scope(SYNTHETIC, (0, 1000))
+    assert "" not in got
+    assert got["pagerank.step"] == pytest.approx(1000e-9)
+    assert got["tocab.gather"] == pytest.approx(300e-9)
+    assert got["scatter-add"] == pytest.approx(550e-9)
+    assert scopes.by_scope([], (0, 1000)) == {}
+
+
+@pytest.mark.parametrize("name", sorted(SLAB))
+def test_slab_reader_reads_its_scope_per_step(name):
+    run = types.SimpleNamespace(
+        scopes=scopes.by_scope(SYNTHETIC, (0, 1000)), steps=[3, 2])
+    want = {"tocab.gather": 300e-9, "tocab.partials": 300e-9,
+            "tocab.reduce": 450e-9}[SLAB[name]] / 5
+    assert _reader(name).read(run) == pytest.approx(want)
+
+
+@pytest.mark.parametrize("name", sorted(SLAB))
+def test_slab_reader_is_none_without_its_scope(name):
+    reader = _reader(name)
+    assert reader.read(types.SimpleNamespace(scopes=None, steps=[3])) is None
+    other = scopes.by_scope([(0, "jit(f)/bfs.level/x:", 0, 10)], (0, 10))
+    assert reader.read(types.SimpleNamespace(scopes=other, steps=[3])) is None

@@ -151,12 +151,17 @@ class Summary:
                 "idle_gaps": [[k, v] for k, v in self.gaps[:top]]}
 
 
+def span(ev: Events, name: str) -> tuple:
+    """Start and end, in ns, of the first host span named ``name``."""
+    spans = [s for s in ev.host_spans if s[0] == name]
+    if not spans:
+        raise RuntimeError(f"no host span {name!r} in the trace")
+    return spans[0][1], spans[0][1] + spans[0][2]
+
+
 def reduce(ev: Events, window: str) -> Summary:
     """Clip ``ev`` to the first host span named ``window`` and reduce it."""
-    spans = [s for s in ev.host_spans if s[0] == window]
-    if not spans:
-        raise RuntimeError(f"no host span {window!r} in the trace")
-    lo, hi = spans[0][1], spans[0][1] + spans[0][2]
+    lo, hi = span(ev, window)
     inner = [s for s in ev.host_spans
              if s[0] != window and s[1] < hi and s[1] + s[2] > lo]
     chips = sorted({o[0] for o in ev.device_ops}) or [0]
