@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.obs.metrics import registry as _obs
 from .graph import DeviceGraph
 from .partition import BlockedGraph
@@ -104,16 +105,19 @@ def _frontier_reach(
     phase).  Both are lowered; `lax.cond` picks at runtime — on TPU the
     pull branch is the blocked kernel, the push branch the flat one.
     ``schedule``/``impl`` must already be concrete (no ``"auto"`` here —
-    the public wrappers resolve them before tracing)."""
+    the public wrappers resolve them before tracing).  The branches are
+    the named scopes ``traversal.pull`` and ``traversal.push``."""
 
     def pull_branch(f):
-        if bg_pull is None:
-            return tocab.baseline_pull(dg, f, reduce="max")
-        return tocab.tocab_pull(bg_pull, f, reduce="max", schedule=schedule,
-                                impl=impl)
+        with jax.named_scope("traversal.pull"):
+            if bg_pull is None:
+                return tocab.baseline_pull(dg, f, reduce="max")
+            return tocab.tocab_pull(bg_pull, f, reduce="max",
+                                    schedule=schedule, impl=impl)
 
     def push_branch(f):
-        return tocab.baseline_push(dg, f, reduce="max")
+        with jax.named_scope("traversal.push"):
+            return tocab.baseline_push(dg, f, reduce="max")
 
     return jax.lax.cond(use_pull, pull_branch, push_branch, frontier_f32)
 
@@ -134,10 +138,17 @@ def bfs(
     pull phase; ``alpha=None`` takes the tuned Beamer α under ``"auto"``
     and the paper's 15 otherwise.
 
+    The call is an ``obs`` span ``bfs`` (resolve and dispatch; the search
+    runs on after it returns), and each level of the compiled loop is the
+    named scope ``bfs.level``.
+
     Returns (depth int32[n], levels int32, push_iters, pull_iters)."""
-    schedule, alpha, impl = _resolve_traversal(
-        bg_pull if bg_pull is not None else dg, schedule, alpha, "bfs", impl)
-    return _bfs_jit(dg, bg_pull, source, max_iters, alpha, schedule, impl)
+    with obs.span("bfs"):
+        schedule, alpha, impl = _resolve_traversal(
+            bg_pull if bg_pull is not None else dg, schedule, alpha, "bfs",
+            impl)
+        return _bfs_jit(dg, bg_pull, source, max_iters, alpha, schedule,
+                        impl)
 
 
 @partial(jax.jit, static_argnames=("max_iters", "alpha", "schedule", "impl"))
@@ -161,19 +172,20 @@ def _bfs_jit(
 
     def body(state):
         depth, frontier, level, (n_push, n_pull) = state
-        # Beamer heuristic: frontier out-edge volume vs m/alpha.
-        m_frontier = (frontier * dg.out_degree.astype(jnp.float32)).sum()
-        use_pull = m_frontier > (dg.m / alpha)
-        _emit_frontier("bfs", frontier, m_frontier, use_pull)
-        reached = _frontier_reach(dg, bg_pull, frontier, use_pull, schedule,
-                                  impl)
-        new_frontier = (reached > 0) & (depth >= INF_DEPTH)
-        depth = jnp.where(new_frontier, level + 1, depth)
-        counts = (
-            n_push + jnp.where(use_pull, 0, 1),
-            n_pull + jnp.where(use_pull, 1, 0),
-        )
-        return depth, new_frontier.astype(jnp.float32), level + 1, counts
+        with jax.named_scope("bfs.level"):
+            # Beamer heuristic: frontier out-edge volume vs m/alpha.
+            m_frontier = (frontier * dg.out_degree.astype(jnp.float32)).sum()
+            use_pull = m_frontier > (dg.m / alpha)
+            _emit_frontier("bfs", frontier, m_frontier, use_pull)
+            reached = _frontier_reach(dg, bg_pull, frontier, use_pull,
+                                      schedule, impl)
+            new_frontier = (reached > 0) & (depth >= INF_DEPTH)
+            depth = jnp.where(new_frontier, level + 1, depth)
+            counts = (
+                n_push + jnp.where(use_pull, 0, 1),
+                n_pull + jnp.where(use_pull, 1, 0),
+            )
+            return depth, new_frontier.astype(jnp.float32), level + 1, counts
 
     depth, _, levels, (n_push, n_pull) = jax.lax.while_loop(
         cond, body, (depth0, frontier0, jnp.int32(0), (jnp.int32(0), jnp.int32(0)))

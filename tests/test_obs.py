@@ -256,6 +256,7 @@ def _compiled(entry: str) -> str:
     from repro.core.graph import DeviceGraph
     from repro.core.pagerank import _pagerank_jit
     from repro.core.partition import build_blocked
+    from repro.core.traversal import DEFAULT_ALPHA, _bfs_jit
 
     rng = np.random.default_rng(5)
     g = G.from_edges(64, rng.integers(0, 64, 400), rng.integers(0, 64, 400))
@@ -264,6 +265,10 @@ def _compiled(entry: str) -> str:
         fn, args = _pagerank_jit, (
             DeviceGraph.from_host(g), build_blocked(g, block_size=16),
             "gc-pull", 0.85, 1e-4, 20, True, "uniform", "slab", False)
+    elif entry == "bfs":
+        fn, args = _bfs_jit, (
+            DeviceGraph.from_host(g), build_blocked(g, block_size=16),
+            jnp.int32(0), 0, DEFAULT_ALPHA, "uniform", "slab")
     elif entry == "push":
         fn, args = jax.jit(tocab.tocab_push), (
             build_blocked(g, block_size=16, direction="push"), x)
@@ -275,8 +280,25 @@ def _compiled(entry: str) -> str:
 
 @pytest.mark.parametrize("entry,names", [
     ("pagerank", SLAB_PHASES | {"pagerank.step"}),
+    ("bfs", SLAB_PHASES | {"traversal.push", "traversal.pull", "bfs.level"}),
     ("push", SLAB_PHASES),
     ("edge_reduce", SLAB_PHASES),
 ])
 def test_compiled_slab_phases_carry_their_scopes(entry, names):
     assert names <= _scope_names(_compiled(entry))
+
+
+def test_bfs_is_one_span():
+    from repro.core import graph as G
+    from repro.core.graph import DeviceGraph
+    from repro.core.partition import build_blocked
+    from repro.core.traversal import bfs
+
+    rng = np.random.default_rng(6)
+    g = G.from_edges(64, rng.integers(0, 64, 300), rng.integers(0, 64, 300))
+    dg, bg = DeviceGraph.from_host(g), build_blocked(g, block_size=16)
+    trace.clear()
+    depth, *_ = bfs(dg, bg, jnp.int32(0))
+    depth.block_until_ready()
+    [ev] = trace.events()
+    assert ev["name"] == "bfs" and ev["parent"] is None
