@@ -10,11 +10,14 @@ Direction optimization (Beamer): iterations with a sparse frontier run in
 **push**; dense-frontier iterations run in **pull** — and only the pull
 iterations go through TOCAB (the working set only exceeds fast memory when
 the frontier is large).  The hybrid switch uses the classic α heuristic on
-the frontier's out-edge count.
+the frontier's out-edge count, which also bounds a push level's work: a
+push level expands only the frontier's CSR rows, into ⌊m/α⌋ arc slots.
 """
 from __future__ import annotations
 
+import math
 import os
+from fractions import Fraction
 from functools import partial
 from typing import Optional
 
@@ -66,8 +69,13 @@ def _callbacks_enabled() -> bool:
     return os.environ.get("REPRO_OBS_DEVICE_CALLBACKS", "1") != "0"
 
 
-def _record_frontier(algo, frontier_size, frontier_edges, use_pull):
+def _record_frontier(algo, budget, frontier_size, frontier_edges, use_pull):
     direction = "pull" if bool(use_pull) else "push"
+    if direction == "push":
+        _obs.histogram(
+            "traversal.push_budget_fill",
+            "push level's frontier out-edges over its arc slots",
+        ).observe(float(frontier_edges) / budget if budget else 0.0, algo=algo)
     _obs.histogram(
         "traversal.frontier_size", "active vertices per iteration"
     ).observe(float(frontier_size), algo=algo)
@@ -83,12 +91,69 @@ def _record_iteration(algo):
     _obs.counter("traversal.iterations", "").inc(algo=algo, direction="pull")
 
 
-def _emit_frontier(algo: str, frontier, m_frontier, use_pull):
+def _emit_frontier(algo: str, frontier, m_frontier, use_pull, budget: int):
     """Trace-time-gated per-iteration telemetry (runtime values arrive on
     the host via debug.callback)."""
     if _callbacks_enabled():
-        jax.debug.callback(partial(_record_frontier, algo),
+        jax.debug.callback(partial(_record_frontier, algo, budget),
                            frontier.sum(), m_frontier, use_pull)
+
+
+def _beamer_switch(dg: DeviceGraph, frontier: jnp.ndarray, alpha: float):
+    """Beamer's direction test of one level, shared by ``bfs`` and ``bc``.
+
+    Returns the frontier's out-edge count m_f (int32, exact), whether the
+    level pulls (m_f > m/α), and the static push budget
+    ``min(m, max(1, ⌊m/α⌋))``.  For an integer m_f, m_f > m/α ⇔
+    m_f > ⌊m/α⌋, with ⌊m/α⌋ taken exactly from the float α, so every level
+    that pushes has at most ``budget`` frontier arcs."""
+    threshold = min(dg.m, math.floor(Fraction(dg.m) / Fraction(alpha)))
+    m_frontier = jnp.where(frontier > 0, dg.out_degree, 0).sum()
+    return m_frontier, m_frontier > threshold, min(dg.m, max(1, threshold))
+
+
+#: Row length of :func:`_scan`.  The v5e compiler's time grows with a scan's
+#: length (~9 s for one over 2M elements, against under 1 s for this
+#: two-level form), and the BFS program is compiled in every process: the key
+#: of JAX's persistent cache holds the address of its per-level callback.
+_SCAN_ROW = 2048
+
+
+def _scan(x: jnp.ndarray, scan, combine) -> jnp.ndarray:
+    """``scan`` (``lax.cumsum`` or ``lax.cummax``) of a 1-D array of
+    non-negative values: rows of ``_SCAN_ROW`` scanned alone, each then
+    joined by ``combine`` with the scan of the rows before it (0 for the
+    first row, padding with 0s)."""
+    size = x.shape[0]
+    rows = -(-size // _SCAN_ROW)
+    y = scan(jnp.pad(x, (0, rows * _SCAN_ROW - size)).reshape(rows, _SCAN_ROW),
+             axis=1)
+    before = jnp.pad(scan(y[:, -1], axis=0)[:-1], (1, 0))
+    return combine(y, before[:, None]).reshape(-1)[:size]
+
+
+def _push_reach(dg: DeviceGraph, frontier: jnp.ndarray, budget: int):
+    """reached[v] = 1 where an arc leaves a frontier vertex for v, else 0.
+
+    The frontier's CSR rows are laid end to end in ``budget`` arc slots,
+    which must hold them all (``_beamer_switch`` sends no larger frontier
+    here): a level costs O(budget + n), not O(m)."""
+    deg = jnp.where(frontier > 0, dg.out_degree, 0)
+    end = _scan(deg, jax.lax.cumsum, jnp.add)
+    start = end - deg
+    # Row v's arc k sits in slot start[v] + k, so it is arc rowptr[v] -
+    # start[v] + slot.  That offset counts the arcs of the non-frontier rows
+    # before v: it never decreases with v, nor do the starts, so a running
+    # max over the slots of the offsets scattered to their rows' starts
+    # gives every slot its row's offset (a row with no arcs shares its
+    # start with the next row, whose offset is at least as large).
+    slot = jnp.arange(budget, dtype=jnp.int32)
+    offset = _scan(jnp.zeros((budget,), jnp.int32).at[start].max(
+        dg.rowptr[:-1] - start, mode="drop", indices_are_sorted=True),
+        jax.lax.cummax, jnp.maximum)
+    arc = jnp.where(slot < end[-1], offset + slot, dg.m)
+    nbr = jnp.take(dg.dst, arc, mode="fill", fill_value=dg.n)
+    return jnp.zeros((dg.n,), jnp.float32).at[nbr].set(1.0, mode="drop")
 
 
 def _frontier_reach(
@@ -96,14 +161,15 @@ def _frontier_reach(
     bg_pull: Optional[BlockedGraph],
     frontier_f32: jnp.ndarray,
     use_pull: jnp.ndarray,
+    budget: int,
     schedule: str = "uniform",
     impl: str = "slab",
 ):
-    """reached[dst] = max over in-edges of frontier[src]  (0/1 floats).
+    """reached[dst] > 0 iff an arc (src, dst) has frontier[src] > 0.
 
-    ``use_pull`` selects TOCAB pull (dense phase) vs flat push (sparse
-    phase).  Both are lowered; `lax.cond` picks at runtime — on TPU the
-    pull branch is the blocked kernel, the push branch the flat one.
+    ``use_pull`` selects TOCAB pull (dense phase) vs the frontier-bounded
+    push of :func:`_push_reach` in ``budget`` arc slots (sparse phase).
+    Both are lowered; `lax.cond` picks at runtime.
     ``schedule``/``impl`` must already be concrete (no ``"auto"`` here —
     the public wrappers resolve them before tracing).  The branches are
     the named scopes ``traversal.pull`` and ``traversal.push``."""
@@ -117,7 +183,7 @@ def _frontier_reach(
 
     def push_branch(f):
         with jax.named_scope("traversal.push"):
-            return tocab.baseline_push(dg, f, reduce="max")
+            return _push_reach(dg, f, budget)
 
     return jax.lax.cond(use_pull, pull_branch, push_branch, frontier_f32)
 
@@ -173,11 +239,9 @@ def _bfs_jit(
     def body(state):
         depth, frontier, level, (n_push, n_pull) = state
         with jax.named_scope("bfs.level"):
-            # Beamer heuristic: frontier out-edge volume vs m/alpha.
-            m_frontier = (frontier * dg.out_degree.astype(jnp.float32)).sum()
-            use_pull = m_frontier > (dg.m / alpha)
-            _emit_frontier("bfs", frontier, m_frontier, use_pull)
-            reached = _frontier_reach(dg, bg_pull, frontier, use_pull,
+            m_frontier, use_pull, budget = _beamer_switch(dg, frontier, alpha)
+            _emit_frontier("bfs", frontier, m_frontier, use_pull, budget)
+            reached = _frontier_reach(dg, bg_pull, frontier, use_pull, budget,
                                       schedule, impl)
             new_frontier = (reached > 0) & (depth >= INF_DEPTH)
             depth = jnp.where(new_frontier, level + 1, depth)
@@ -237,11 +301,10 @@ def _bc_jit(
 
     def fwd_body(state):
         depth, sigma, frontier, level = state
-        m_frontier = (frontier * dg.out_degree.astype(jnp.float32)).sum()
-        use_pull = m_frontier > (dg.m / alpha)
-        _emit_frontier("bc", frontier, m_frontier, use_pull)
-        reached = _frontier_reach(dg, bg_pull, frontier, use_pull, schedule,
-                                  impl)
+        m_frontier, use_pull, budget = _beamer_switch(dg, frontier, alpha)
+        _emit_frontier("bc", frontier, m_frontier, use_pull, budget)
+        reached = _frontier_reach(dg, bg_pull, frontier, use_pull, budget,
+                                  schedule, impl)
         new_frontier = (reached > 0) & (depth >= INF_DEPTH)
         depth = jnp.where(new_frontier, level + 1, depth)
         # σ[dst] += Σ σ[src] over tree edges (src on frontier level).

@@ -136,9 +136,9 @@ class SearchSpace:
         """Valid candidates for ``workload``, deterministic order.
 
         Traversal (``bfs``) explores α and restricts the blocked phase to
-        pull (the sparse phase is always flat push); ``cb`` exists only as
-        the paper's pull strawman; ``balanced``/``dense_impl``/thresholds
-        only apply to TOCAB engines."""
+        pull (the sparse phase is always the frontier-bounded push); ``cb``
+        exists only as the paper's pull strawman;
+        ``balanced``/``dense_impl``/thresholds only apply to TOCAB engines."""
         if workload not in WORKLOADS:
             raise ValueError(f"unknown workload {workload!r}; "
                              f"expected one of {WORKLOADS}")

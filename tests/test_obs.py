@@ -168,6 +168,29 @@ def test_engines_populate_registry():
     assert total == int(n_push) + int(n_pull)
 
 
+def test_push_budget_fill_recorded_once_per_push_level():
+    from repro.core import graph as G
+    from repro.core.graph import DeviceGraph
+    from repro.core.partition import build_blocked
+    from repro.core import traversal
+
+    rng = np.random.default_rng(0)
+    g = G.from_edges(64, rng.integers(0, 64, 300), rng.integers(0, 64, 300))
+    dg = DeviceGraph.from_host(g)
+    bg = build_blocked(g, block_size=16, direction="pull")
+    fill = registry.histogram("traversal.push_budget_fill")
+
+    def seen():
+        return fill.stats(algo="bfs") or {"count": 0, "max": 0.0}
+
+    before = seen()["count"]
+    depth, levels, n_push, n_pull = traversal.bfs(dg, bg, jnp.int32(0))
+    depth.block_until_ready()
+    assert int(n_push) >= 1 and int(n_pull) >= 1
+    assert seen()["count"] - before == int(n_push)
+    assert 0.0 <= seen()["min"] and seen()["max"] <= 1.0
+
+
 
 # ------------------- spans on the profiler's clock ------------------- #
 def _host_events(trace_dir) -> list:
