@@ -232,8 +232,8 @@ def fig11_blocksize():
     autotuner's trial runner (same warmup/median-of-k spans the tuner
     records) next to the cache model's prediction for each block size.
     Paper picks 256 vertices for a 2.75MB GPU L2; the sweep shows the same
-    U-shape — and the row whose ``chosen=1`` is what ``schedule="auto"``
-    would pick."""
+    U-shape — and the row whose ``chosen=1`` is what a tuned call
+    (``engine_kwargs``) would pick."""
     from repro.tune import Candidate, run_trial
     from repro.tune.analytic import predicted_cost
 

@@ -26,7 +26,7 @@ call separates them, and what was checked):
               on rmat14, and the balanced schedule's Pallas dense bin on
               rmat14 (every block forced dense) and on the balance-mix
               graph; each against the slab engine on the same chip.
-6. checks   — no ``resilience.fallbacks`` recorded, peak device memory.
+6. checks   — peak device memory.
 
 The last line of stdout is ``{"ok": true, "device": {...}}`` and is printed
 only when every phase passed.  JAX's compilation cache goes where
@@ -188,7 +188,7 @@ def phase_pagerank(g, dg, bg):
 
     (rank, iters), first, second = timed_twice(lambda: pagerank(
         dg, bg, variant="gc-pull", tol=PR_TOL, schedule="uniform",
-        impl="slab", allow_fallback=False))
+        impl="slab"))
     iters = int(iters)
     t0 = time.perf_counter()
     ref, ref_iters = pagerank_ref(host_csr(g, weighted=False), g.out_degree,
@@ -247,8 +247,7 @@ def phase_spmv(g, dg, bg, bgp, seed: int):
     xd = jnp.asarray(x)
     for variant, b in (("gc-pull", bg), ("gc-push", bgp)):
         y, first, second = timed_twice(lambda: spmv(
-            dg, b, xd, variant=variant, schedule="uniform", impl="slab",
-            allow_fallback=False))
+            dg, b, xd, variant=variant, schedule="uniform", impl="slab"))
         msg = check_close(f"spmv {variant}", y, ref, rtol=1e-4, atol=1e-5)
         log("spmv", variant=variant, wall_s=f"{second:.4f}",
             compile_s=f"{first - second:.1f}", check=f"ok ({msg})")
@@ -290,21 +289,19 @@ def phase_pallas(seed: int):
         for label, bgx, fn in ((f"{name} fused pull", pull, tocab_pull),
                                (f"{name} fused push", push, tocab_push)):
             run(label,
-                lambda v, b=bgx, f=fn: f(b, v, impl="fused",
-                                         allow_fallback=False),
+                lambda v, b=bgx, f=fn: f(b, v, impl="fused"),
                 lambda v, b=bgx, f=fn: f(b, v), x)
             run(label + " min-plus",
                 lambda v, b=bgx, f=fn: f(b, v, reduce="min", combine=minplus,
-                                         impl="fused", allow_fallback=False),
+                                         impl="fused"),
                 lambda v, b=bgx, f=fn: f(b, v, reduce="min",
                                          combine=minplus), x, exact=True)
         if name != "rmat14":
             continue  # one fused PageRank; each slab PageRank compiles ~1 min
         dg = DeviceGraph.from_host(g)
         (r_f, it_f), first, second = timed_twice(lambda: pagerank(
-            dg, pull, tol=PR_TOL, impl="fused", allow_fallback=False))
-        r_s, it_s = pagerank(dg, pull, tol=PR_TOL, impl="slab",
-                             allow_fallback=False)
+            dg, pull, tol=PR_TOL, impl="fused"))
+        r_s, it_s = pagerank(dg, pull, tol=PR_TOL, impl="slab")
         assert abs(int(it_f) - int(it_s)) <= 1, (int(it_f), int(it_s))
         msg = check_close(f"{name} fused pagerank", r_f, r_s, rtol=1e-5,
                           atol=1e-9)
@@ -324,8 +321,7 @@ def phase_pallas(seed: int):
         x = jnp.asarray(np.random.default_rng(seed).random(g.n, np.float32))
         run(label,
             lambda v, b=b: tocab_pull(b, v, schedule="balanced",
-                                      dense_impl="pallas",
-                                      allow_fallback=False),
+                                      dense_impl="pallas"),
             lambda v, b=b: tocab_pull(b, v), x)
 
 
@@ -336,10 +332,9 @@ def main() -> int:
 
     if not os.path.isdir(os.path.join(SRC, "repro")):
         sys.exit(f"chip_smoke: the repository's sources are not at {SRC}")
-    for var in ("REPRO_CHAOS", "REPRO_RESILIENCE_FALLBACK"):
-        if os.environ.get(var):
-            sys.exit(f"chip_smoke: {var} is set; the smoke runs no fault "
-                     "injection and no fallback")
+    if os.environ.get("REPRO_CHAOS"):
+        sys.exit("chip_smoke: REPRO_CHAOS is set; the smoke runs no fault "
+                 "injection")
     sys.path.insert(0, SRC)
     import jax
 
@@ -348,7 +343,6 @@ def main() -> int:
         sys.exit(f"chip_smoke: no TPU — jax.devices()[0].platform is "
                  f"{dev.platform!r}; this smoke runs only on the chip")
     from repro.compile_cache import use_compile_cache
-    from repro.obs.metrics import registry
 
     log("setup", device=dev.device_kind, count=len(jax.devices()),
         jax=jax.__version__, compile_cache=use_compile_cache())
@@ -360,12 +354,8 @@ def main() -> int:
     del g, dg, bg, bgp
     phase_pallas(args.seed)
 
-    fallbacks = registry.snapshot().get("resilience.fallbacks")
-    assert not (fallbacks and fallbacks["series"]), (
-        f"resilience fallbacks recorded: {fallbacks}")
     assert "repro.launch.dryrun" not in sys.modules
-    log("checks", resilience_fallbacks=0,
-        peak_bytes_in_use=memory_stat("peak_bytes_in_use"),
+    log("checks", peak_bytes_in_use=memory_stat("peak_bytes_in_use"),
         total_s=f"{time.perf_counter() - t0:.1f}", check="ok")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

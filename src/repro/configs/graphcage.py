@@ -32,13 +32,11 @@ class GraphCageCfg:
     tune_alphas: tuple = (4.0, 15.0, 64.0)
     tune_impls: tuple = ("slab", "fused")
     tune_db_dir: str = "experiments/tune"
-    # resilience (repro.resilience) — retry budget for IO paths, the
-    # per-candidate tuner wall-clock bound (None = unbounded), and whether
-    # explicitly-requested impls may degrade down the engine ladder
+    # resilience (repro.resilience) — retry budget for IO paths and the
+    # per-candidate tuner wall-clock bound (None = unbounded)
     retry_attempts: int = 3
     retry_base_delay: float = 0.05
     trial_timeout_s: Optional[float] = None
-    allow_engine_fallback: Optional[bool] = None  # None → env/impl-derived
 
 
 DEFAULT = GraphCageCfg()

@@ -327,7 +327,7 @@ def _reduce_msgs_scan(row_budget, cidx, mask, msgs, reduce, chunk: int = 256):
 
 
 def _reduce_msgs_onehot(row_budget, cidx, mask, msgs, chunk: int = 256):
-    """Dense-bin fallback tile path: scatter expressed as chunked one-hot
+    """Dense-bin tile path (the default off TPU): scatter as chunked one-hot
     matmuls (sum semiring only) — the MXU-native shape, pure JAX.  The
     one-hot width is the *bin's* row budget, not the global local_budget:
     dense blocks compact to few distinct rows, so the matmul stays small."""
@@ -394,37 +394,16 @@ def bin_pull_partials(
         return None
     rb = _compact_budget(sched, bin_id, bg.local_budget)
     if bin_id == BIN_DENSE and _dense_eligible(reduce, combine):
-        impl = dense_impl or default_dense_impl()
+        if (dense_impl or default_dense_impl()) == "pallas":
+            from repro.kernels.tocab_spmm.ops import tocab_spmm_partials
 
-        def _onehot():
-            cidx, mask, msgs = _pull_msgs(bg, ids, values, reduce, combine)
-            return _reduce_msgs_onehot(rb, cidx, mask, msgs)
-
-        if impl == "pallas":
-            from repro.resilience import chaos, degrade
-
-            def _pallas():
-                chaos.maybe_raise("kernel.tocab_spmm")
-                from repro.kernels.tocab_spmm.ops import tocab_spmm_partials
-
-                itp = (interpret if interpret is not None
-                       else jax.default_backend() != "tpu")
-                return tocab_spmm_partials(
-                    bg, values, block_ids=ids, local_budget=rb,
-                    unweighted=combine is UNWEIGHTED, interpret=itp)
-
-            # backend-picked pallas (dense_impl=None) may degrade to the
-            # one-hot matmul; an explicitly requested pallas only under
-            # REPRO_RESILIENCE_FALLBACK
-            allow = degrade.fallback_allowed(
-                "auto" if dense_impl is None else dense_impl, None)
-            if allow:
-                return degrade.dispatch(
-                    "tocab_spmm", bg.fingerprint,
-                    [("pallas", _pallas), ("onehot", _onehot)],
-                    allow_fallback=True)
-            return _pallas()
-        return _onehot()
+            itp = (interpret if interpret is not None
+                   else jax.default_backend() != "tpu")
+            return tocab_spmm_partials(
+                bg, values, block_ids=ids, local_budget=rb,
+                unweighted=combine is UNWEIGHTED, interpret=itp)
+        cidx, mask, msgs = _pull_msgs(bg, ids, values, reduce, combine)
+        return _reduce_msgs_onehot(rb, cidx, mask, msgs)
     cidx, mask, msgs = _pull_msgs(bg, ids, values, reduce, combine)
     if bin_id == BIN_SPARSE:
         return _reduce_msgs_sparse(rb, cidx, mask, msgs, reduce)

@@ -28,7 +28,7 @@ PR_VARIANTS = ("base", "push", "cb", "gc-pull", "gc-push")
 
 
 def _gather_sums(variant: str, dg, bg, contributions, schedule="uniform",
-                 impl="slab", epilogue=None, allow_fallback=None):
+                 impl="slab", epilogue=None):
     # PR is unweighted: the UNWEIGHTED sentinel combine ignores any edge
     # values the graph carries (and keeps the dense tile path eligible).
     kw = dict(reduce="sum", combine=_unweighted)
@@ -40,12 +40,10 @@ def _gather_sums(variant: str, dg, bg, contributions, schedule="uniform",
         return tocab.cb_pull(bg, contributions, **kw)
     if variant == "gc-pull":
         return tocab.tocab_pull(bg, contributions, schedule=schedule,
-                                impl=impl, epilogue=epilogue,
-                                allow_fallback=allow_fallback, **kw)
+                                impl=impl, epilogue=epilogue, **kw)
     if variant == "gc-push":
         return tocab.tocab_push(bg, contributions, schedule=schedule,
-                                impl=impl, epilogue=epilogue,
-                                allow_fallback=allow_fallback, **kw)
+                                impl=impl, epilogue=epilogue, **kw)
     raise ValueError(f"unknown PR variant {variant!r}")
 
 
@@ -59,7 +57,6 @@ def pagerank_iteration(
     handle_dangling: bool = True,
     schedule: str = "uniform",
     impl: str = "slab",
-    allow_fallback=None,
 ):
     """One PR iteration: contributions → gather/scatter → apply.
 
@@ -77,8 +74,7 @@ def pagerank_iteration(
     if variant in ("gc-pull", "gc-push"):
         add = (1.0 - damping) / n + damping * (dangling / n)
         return _gather_sums(variant, dg, bg, contributions, schedule,
-                            impl, epilogue=(damping, add),
-                            allow_fallback=allow_fallback)
+                            impl, epilogue=(damping, add))
     sums = _gather_sums(variant, dg, bg, contributions, schedule)
     return (1.0 - damping) / n + damping * (sums + dangling / n)
 
@@ -93,44 +89,28 @@ def pagerank(
     handle_dangling: bool = True,
     schedule: str = "uniform",
     impl: str = "slab",
-    allow_fallback=None,
 ):
     """Iterate PR until the L1 delta falls below ``tol``.
 
-    Returns (rank, iterations).  ``schedule="auto"`` / ``impl="auto"``
-    consult the tuning DB (``repro.tune``) via the graph's build-time
-    fingerprint; resolution happens here, outside jit, so the jit cache is
-    keyed on the concrete choices and a re-tune takes effect on the next
-    call.  ``impl="auto"`` (or ``allow_fallback=True``) also arms the
-    fused→slab→reference degradation ladder: a kernel-dispatch failure at
-    trace time degrades the engine instead of crashing the run, and the
-    memoized verdict (``repro.resilience.degrade``) pins later calls for
-    this graph straight to the working rung.
+    Returns (rank, iterations).  ``schedule`` and ``impl`` are the engine of
+    the gc variants, as in :func:`~repro.core.tocab.tocab_pull`; a tuned
+    call reads them from the tuning DB first, through the tuner's
+    ``engine_kwargs``: ``pagerank(dg, bg, **engine_kwargs(bg, "pagerank"))``.
 
-    The call is an ``obs`` span ``pagerank`` (resolve and dispatch; the
-    solve runs on after it returns), and each step of the compiled loop
-    is the named scope ``pagerank.step``."""
-    from repro.resilience import degrade
-
+    The call is an ``obs`` span ``pagerank`` (dispatch; the solve runs on
+    after it returns), and each step of the compiled loop is the named
+    scope ``pagerank.step``."""
     with obs.span("pagerank"):
-        obj = bg if bg is not None else dg
-        rs = tocab.resolve_schedule(obj, schedule, workload="pagerank")
-        ri = tocab.resolve_impl(obj, impl, workload="pagerank")
-        rs, ri = tocab._reconcile_fused(rs, ri, schedule, impl)
-        allow = degrade.fallback_allowed(impl, allow_fallback)
-        if allow and bg is not None and variant in ("gc-pull", "gc-push"):
-            site = "tocab_pull" if variant == "gc-pull" else "tocab_push"
-            ri = degrade.apply_verdict(bg.fingerprint, site, ri)
         return _pagerank_jit(
-            dg, bg, variant, damping, tol, max_iters, handle_dangling, rs,
-            ri, allow)
+            dg, bg, variant, damping, tol, max_iters, handle_dangling,
+            schedule, impl)
 
 
 @partial(
     jax.jit,
     static_argnames=(
         "variant", "damping", "tol", "max_iters", "handle_dangling",
-        "schedule", "impl", "allow_fallback",
+        "schedule", "impl",
     ),
 )
 def _pagerank_jit(
@@ -143,7 +123,6 @@ def _pagerank_jit(
     handle_dangling: bool,
     schedule: str,
     impl: str = "slab",
-    allow_fallback: bool = False,
 ):
     n = dg.n
     rank0 = jnp.full((n,), 1.0 / n, jnp.float32)
@@ -157,7 +136,7 @@ def _pagerank_jit(
         with jax.named_scope("pagerank.step"):
             new_rank = pagerank_iteration(
                 variant, dg, bg, rank, dg.out_degree, damping,
-                handle_dangling, schedule, impl, allow_fallback,
+                handle_dangling, schedule, impl,
             )
             return new_rank, jnp.abs(new_rank - rank).sum(), it + 1
 

@@ -94,7 +94,7 @@ class BlockedGraph:
     schedule: Optional[object] = dataclasses.field(
         default=None, metadata=dict(static=True))
     # structural fingerprint of the source graph (tuning-db key); static so
-    # schedule="auto" can resolve a tuned plan even at trace time.
+    # a tuned plan resolves from the layout alone, even at trace time.
     fingerprint: Optional[str] = dataclasses.field(
         default=None, metadata=dict(static=True))
 

@@ -29,35 +29,21 @@ def spmv(
     dense_impl: Optional[str] = None,
     impl: str = "slab",
     scale=None,
-    allow_fallback=None,
 ):
     """y[dst] = Σ_{(src,dst)} A[src,dst]·x[src].
 
     ``x`` may be a vector (n,) — SpMV — or a matrix (n, d) — SpMM, which is
     the GNN aggregation primitive.  ``schedule='balanced'`` runs the blocked
-    variants with sparsity-aware per-bin strategies; ``schedule='auto'`` /
-    ``impl='auto'`` consult the tuning DB (resolved here, outside jit).
-    ``dense_impl`` forces the balanced dense-bin backend (``'pallas'`` /
-    ``'onehot'``); ``impl='fused'`` routes the gc variants through the
-    persistent no-partial-slab pipeline.  ``scale`` fuses ``y*scale`` into
-    the engine epilogue (gc variants).  ``impl='auto'`` (or
-    ``allow_fallback=True``) arms the fused→slab→reference degradation
-    ladder on the gc variants."""
-    from repro.resilience import degrade
-
-    obj = bg if bg is not None else dg
-    rs = tocab.resolve_schedule(obj, schedule, workload="spmv")
-    ri = tocab.resolve_impl(obj, impl, workload="spmv")
-    rs, ri = tocab._reconcile_fused(rs, ri, schedule, impl)
-    allow = degrade.fallback_allowed(impl, allow_fallback)
-    if allow and bg is not None and variant in ("gc-pull", "gc-push"):
-        site = "tocab_pull" if variant == "gc-pull" else "tocab_push"
-        ri = degrade.apply_verdict(bg.fingerprint, site, ri)
-    return _spmv_jit(dg, bg, x, variant, rs, dense_impl, ri, scale, allow)
+    variants with sparsity-aware per-bin strategies.  ``dense_impl``
+    forces the balanced dense-bin backend (``'pallas'`` / ``'onehot'``);
+    ``impl='fused'`` routes the gc variants through the persistent
+    no-partial-slab pipeline.  ``scale`` fuses ``y*scale`` into the engine
+    epilogue (gc variants)."""
+    return _spmv_jit(dg, bg, x, variant, schedule, dense_impl, impl, scale)
 
 
 @partial(jax.jit, static_argnames=("variant", "schedule", "dense_impl",
-                                   "impl", "allow_fallback"))
+                                   "impl"))
 def _spmv_jit(
     dg: DeviceGraph,
     bg: Optional[BlockedGraph],
@@ -67,7 +53,6 @@ def _spmv_jit(
     dense_impl: Optional[str],
     impl: str = "slab",
     scale=None,
-    allow_fallback: bool = False,
 ):
     epilogue = None if scale is None else (scale, 0.0)
     if variant == "base":
@@ -79,12 +64,10 @@ def _spmv_jit(
     elif variant == "gc-pull":
         return tocab.tocab_pull(bg, x, reduce="sum", schedule=schedule,
                                 dense_impl=dense_impl, impl=impl,
-                                epilogue=epilogue,
-                                allow_fallback=allow_fallback)
+                                epilogue=epilogue)
     elif variant == "gc-push":
         return tocab.tocab_push(bg, x, reduce="sum", schedule=schedule,
-                                impl=impl, epilogue=epilogue,
-                                allow_fallback=allow_fallback)
+                                impl=impl, epilogue=epilogue)
     else:
         raise ValueError(f"unknown SpMV variant {variant!r}")
     return y if scale is None else y * scale

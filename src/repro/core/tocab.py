@@ -42,8 +42,6 @@ from .partition import REDUCE_IDENTITY, BlockedGraph
 
 __all__ = [
     "segment_reduce",
-    "resolve_schedule",
-    "resolve_impl",
     "baseline_pull",
     "baseline_push",
     "cb_pull",
@@ -236,45 +234,19 @@ def reduce_partials(bg: BlockedGraph, partials: jnp.ndarray, reduce: str = "sum"
         return out[:-1]
 
 
-def resolve_schedule(bg, schedule: str, workload: str = "spmv") -> str:
-    """``"auto"`` → the tuned plan's schedule for this graph (``repro.tune``
-    DB keyed by the BlockedGraph's build-time fingerprint — static, so this
-    is safe even at jit trace time), anything else passes through."""
-    if schedule != "auto":
-        return schedule
-    from repro.tune.plan import resolve_schedule as _resolve
-
-    return _resolve(bg, workload=workload)
-
-
-def resolve_impl(bg, impl: str, workload: str = "spmv") -> str:
-    """``"auto"`` → the tuned plan's engine implementation (``"slab"`` or
-    ``"fused"``) for this graph, anything else passes through.  Like
-    :func:`resolve_schedule` this keys on the BlockedGraph's static
-    fingerprint, so it is safe at jit trace time."""
-    if impl != "auto":
-        return impl
-    from repro.tune.plan import resolve_impl as _resolve
-
-    return _resolve(bg, workload=workload)
-
-
-def _reconcile_fused(schedule: str, impl: str,
-                     schedule_arg: str, impl_arg: str):
-    """``fused`` × ``balanced`` is not a valid pairing — the fused pipeline
-    runs every block through one resident-accumulator kernel (its bin
-    awareness is a visit *order*, not per-bin strategies).  Whichever side
-    the tuner picked (``"auto"``) yields; an explicit conflict is an
-    error."""
+def _check_engine(schedule: str, impl: str):
+    """The caller's ``schedule`` and ``impl`` are the engine.  ``fused`` ×
+    ``balanced`` is the one invalid pairing: the fused pipeline runs every
+    block through one resident-accumulator kernel (its bin awareness is a
+    visit *order*, not per-bin strategies)."""
+    if schedule not in ("uniform", "balanced"):
+        raise ValueError(f"unknown schedule {schedule!r}")
+    if impl not in ("fused", "slab"):
+        raise ValueError(f"unknown impl {impl!r}")
     if impl == "fused" and schedule == "balanced":
-        if impl_arg == "auto":
-            return schedule, "slab"
-        if schedule_arg == "auto":
-            return "uniform", impl
         raise ValueError(
             "impl='fused' is incompatible with schedule='balanced' — use "
-            "schedule='uniform' (or 'auto') with the fused pipeline")
-    return schedule, impl
+            "schedule='uniform' with the fused pipeline")
 
 
 def _slab_epilogue(out, reduce: str, epilogue):
@@ -289,32 +261,6 @@ def _slab_epilogue(out, reduce: str, epilogue):
             f"semiring supports it, got reduce={reduce!r}")
     mul, add = epilogue
     return out * mul + add
-
-
-def _ladder_dispatch(engine: str, bg, ri: str, allow: bool, fused_thunk,
-                     slab_thunk, reference_thunk):
-    """Degradation-ladder dispatch for one engine call (see
-    :mod:`repro.resilience.degrade`).  ``engine`` is the dispatch-site
-    label (``tocab_pull``/``tocab_push``/``tocab_edge_reduce``);
-    fingerprint-keyed verdicts make the fallback a once-per-(graph,
-    engine) decision, not a per-iteration one."""
-    from repro.resilience import degrade
-
-    rungs = []
-    if ri == "fused":
-        rungs.append(("fused", fused_thunk))
-    if ri in ("fused", "slab"):
-        rungs.append(("slab", slab_thunk))
-    if reference_thunk is not None:
-        rungs.append(("reference", reference_thunk))
-    if not rungs or ri not in ("fused", "slab", "reference"):
-        raise ValueError(f"unknown impl {ri!r}")
-    if ri == "reference":
-        return reference_thunk()
-    if not allow:
-        return rungs[0][1]()
-    return degrade.dispatch(engine, bg.fingerprint, rungs,
-                            allow_fallback=True)
 
 
 @partial(jax.jit, static_argnames=("reduce", "combine", "schedule",
@@ -332,8 +278,6 @@ def _tocab_pull_jit(
 
         return balanced_pull(bg, values, reduce, combine,
                              dense_impl=dense_impl)
-    if schedule != "uniform":
-        raise ValueError(f"unknown schedule {schedule!r}")
     _record_engine("tocab_pull", "pull")
     partials = tocab_pull_partials(bg, values, reduce, combine)
     return reduce_partials(bg, partials, reduce)
@@ -348,58 +292,28 @@ def tocab_pull(
     dense_impl: Optional[str] = None,
     impl: str = "slab",
     epilogue=None,
-    allow_fallback: Optional[bool] = None,
 ):
     """``schedule='uniform'`` processes every block with the same segmented
     reduce; ``'balanced'`` dispatches each sparsity bin of the build-time
-    :class:`~repro.core.balance.BlockSchedule` to its matched strategy;
-    ``'auto'`` resolves uniform/balanced from the ``repro.tune`` tuning DB
-    (falling back to uniform when this graph was never tuned).
+    :class:`~repro.core.balance.BlockSchedule` to its matched strategy.
     ``dense_impl`` forces the balanced dense-bin backend ('pallas' /
     'onehot'; default picks per backend).
 
     ``impl='fused'`` routes through the persistent single-kernel pipeline
     (``repro.kernels.tocab_fused``): no partial slab in HBM, bit-identical
-    results; ``'auto'`` consults the tuning DB.  ``epilogue=(mul, add)``
-    fuses the per-vertex apply step ``out*mul + add`` (sum semiring only) —
-    the slab path applies the identical expression as a trailing pass.
-
-    ``allow_fallback`` arms the fused→slab→reference degradation ladder
-    (:mod:`repro.resilience.degrade`); default ``None`` means on for
-    ``impl='auto'`` and env-gated for explicit impls."""
-    from repro.resilience import chaos, degrade
-
-    rs = resolve_schedule(bg, schedule)
-    ri = resolve_impl(bg, impl)
-    rs, ri = _reconcile_fused(rs, ri, schedule, impl)
-    allow = degrade.fallback_allowed(impl, allow_fallback)
-    if allow:
-        ri = degrade.apply_verdict(bg.fingerprint, "tocab_pull", ri)
-
-    def _fused():
-        chaos.maybe_raise("kernel.tocab_fused")
+    results.  ``epilogue=(mul, add)`` fuses the per-vertex apply step
+    ``out*mul + add`` (sum semiring only) — the slab path applies the
+    identical expression as a trailing pass.  A kernel that fails to lower
+    or compile raises; nothing falls back to another engine."""
+    _check_engine(schedule, impl)
+    if impl == "fused":
         from repro.kernels.tocab_fused import fused_pull
 
         _record_engine("tocab_pull_fused", "pull")
         return fused_pull(bg, values, reduce, combine, epilogue)
-
-    def _slab():
-        if allow:
-            chaos.maybe_raise("kernel.tocab_slab")
-        out = _tocab_pull_jit(bg, values, reduce=reduce, combine=combine,
-                              schedule=rs, dense_impl=dense_impl)
-        return _slab_epilogue(out, reduce, epilogue)
-
-    def _reference():
-        # eager uniform dataflow, no jax.jit anywhere on the way down —
-        # survives backend lowering/compile failures by construction
-        _record_engine("tocab_pull_reference", "pull")
-        partials = tocab_pull_partials(bg, values, reduce, combine)
-        return _slab_epilogue(reduce_partials(bg, partials, reduce),
-                              reduce, epilogue)
-
-    return _ladder_dispatch("tocab_pull", bg, ri, allow, _fused, _slab,
-                            _reference)
+    out = _tocab_pull_jit(bg, values, reduce=reduce, combine=combine,
+                          schedule=schedule, dense_impl=dense_impl)
+    return _slab_epilogue(out, reduce, epilogue)
 
 
 @partial(jax.jit, static_argnames=("reduce", "combine", "schedule"))
@@ -415,21 +329,7 @@ def _tocab_push_jit(
         from .balance import balanced_push
 
         return balanced_push(bg, values, reduce, combine)
-    if schedule != "uniform":
-        raise ValueError(f"unknown schedule {schedule!r}")
-    return _tocab_push_uniform(bg, values, reduce, combine)
-
-
-def _tocab_push_uniform(
-    bg: BlockedGraph,
-    values: jnp.ndarray,
-    reduce: str = "sum",
-    combine: Optional[Callable] = None,
-    engine: str = "tocab_push",
-):
-    """Uniform push body — shared by the jitted wrapper above and the
-    eager ``reference`` rung of the degradation ladder."""
-    _record_engine(engine, "push")
+    _record_engine("tocab_push", "push")
     # Gather each unique source's value once per block (the data-reuse win).
     with jax.named_scope("tocab.partials"):
         block_contrib = jnp.take(values, bg.id_map, axis=0, mode="fill",
@@ -475,46 +375,23 @@ def tocab_push(
     schedule: str = "uniform",
     impl: str = "slab",
     epilogue=None,
-    allow_fallback: Optional[bool] = None,
 ):
     """Push (Alg. 5): block by destination range; contributions of the few
     distinct sources of a block are fetched *once* through ``id_map``
     (block_contrib slab), then fanned out per edge; accumulation is confined
     to the block's destination window (conflict-free, no atomics on TPU).
-    ``schedule`` as in :func:`tocab_pull` (including ``'auto'``); ``impl``,
-    ``epilogue`` and ``allow_fallback`` as in :func:`tocab_pull` — the fused
-    push visits blocks in the balance module's bin-major order (disjoint
-    destination windows keep that bit-identical)."""
-    from repro.resilience import chaos, degrade
-
-    rs = resolve_schedule(bg, schedule)
-    ri = resolve_impl(bg, impl)
-    rs, ri = _reconcile_fused(rs, ri, schedule, impl)
-    allow = degrade.fallback_allowed(impl, allow_fallback)
-    if allow:
-        ri = degrade.apply_verdict(bg.fingerprint, "tocab_push", ri)
-
-    def _fused():
-        chaos.maybe_raise("kernel.tocab_fused")
+    ``schedule``, ``impl`` and ``epilogue`` as in :func:`tocab_pull` — the
+    fused push visits blocks in the balance module's bin-major order
+    (disjoint destination windows keep that bit-identical)."""
+    _check_engine(schedule, impl)
+    if impl == "fused":
         from repro.kernels.tocab_fused import fused_push
 
         _record_engine("tocab_push_fused", "push")
         return fused_push(bg, values, reduce, combine, epilogue)
-
-    def _slab():
-        if allow:
-            chaos.maybe_raise("kernel.tocab_slab")
-        out = _tocab_push_jit(bg, values, reduce=reduce, combine=combine,
-                              schedule=rs)
-        return _slab_epilogue(out, reduce, epilogue)
-
-    def _reference():
-        out = _tocab_push_uniform(bg, values, reduce, combine,
-                                  engine="tocab_push_reference")
-        return _slab_epilogue(out, reduce, epilogue)
-
-    return _ladder_dispatch("tocab_push", bg, ri, allow, _fused, _slab,
-                            _reference)
+    out = _tocab_push_jit(bg, values, reduce=reduce, combine=combine,
+                          schedule=schedule)
+    return _slab_epilogue(out, reduce, epilogue)
 
 
 # ====================================================================== #
@@ -526,18 +403,6 @@ def blocked_edge_values(bg: BlockedGraph, flat_vals: jnp.ndarray) -> jnp.ndarray
     return jnp.take(flat_vals, bg.edge_perm, axis=0, mode="fill", fill_value=0)
 
 
-def _edge_reduce_uniform(bg: BlockedGraph, flat_edge_vals, reduce: str):
-    """Uniform edge-reduce body (eager; shared by slab and reference)."""
-    with jax.named_scope("tocab.gather"):
-        vals = blocked_edge_values(bg, flat_edge_vals)
-        ident = jnp.asarray(REDUCE_IDENTITY[reduce], vals.dtype)
-        mask = bg.edge_mask
-        while mask.ndim < vals.ndim:
-            mask = mask[..., None]
-        vals = jnp.where(mask, vals, ident)
-    return reduce_partials(bg, _block_partials(bg, vals, reduce), reduce)
-
-
 def tocab_edge_reduce(
     bg: BlockedGraph,
     flat_edge_vals: jnp.ndarray,  # (m, ...) in original edge order
@@ -545,51 +410,31 @@ def tocab_edge_reduce(
     schedule: str = "uniform",
     impl: str = "slab",
     epilogue=None,
-    allow_fallback: Optional[bool] = None,
 ):
     """Reduce *edge* values to the compacted side (dst for pull layout)
     through the partial-slab + reduction machinery — the GNN primitive
-    (edge messages → node aggregate) in TOCAB form.  ``impl`` /
-    ``epilogue`` / ``allow_fallback`` as in :func:`tocab_pull`."""
-    from repro.resilience import chaos, degrade
-
-    rs = resolve_schedule(bg, schedule)
-    ri = resolve_impl(bg, impl)
-    schedule, ri = _reconcile_fused(rs, ri, schedule, impl)
-    allow = degrade.fallback_allowed(impl, allow_fallback)
-    if allow:
-        ri = degrade.apply_verdict(bg.fingerprint, "tocab_edge_reduce", ri)
-    if schedule not in ("uniform", "balanced"):
-        raise ValueError(f"unknown schedule {schedule!r}")
-
-    def _fused():
-        chaos.maybe_raise("kernel.tocab_fused")
+    (edge messages → node aggregate) in TOCAB form.  ``schedule``, ``impl``
+    and ``epilogue`` as in :func:`tocab_pull`."""
+    _check_engine(schedule, impl)
+    if impl == "fused":
         from repro.kernels.tocab_fused import fused_edge_reduce
 
         _record_engine("tocab_edge_reduce_fused", bg.direction)
         return fused_edge_reduce(bg, flat_edge_vals, reduce, epilogue)
+    if schedule == "balanced":
+        from .balance import balanced_edge_reduce
 
-    def _slab():
-        if allow:
-            chaos.maybe_raise("kernel.tocab_slab")
-        if schedule == "balanced":
-            from .balance import balanced_edge_reduce
-
-            return _slab_epilogue(
-                balanced_edge_reduce(bg, flat_edge_vals, reduce), reduce,
-                epilogue)
-        return _slab_epilogue(
-            _edge_reduce_uniform(bg, flat_edge_vals, reduce), reduce,
-            epilogue)
-
-    def _reference():
-        _record_engine("tocab_edge_reduce_reference", bg.direction)
-        return _slab_epilogue(
-            _edge_reduce_uniform(bg, flat_edge_vals, reduce), reduce,
-            epilogue)
-
-    return _ladder_dispatch("tocab_edge_reduce", bg, ri, allow, _fused,
-                            _slab, _reference)
+        out = balanced_edge_reduce(bg, flat_edge_vals, reduce)
+    else:
+        with jax.named_scope("tocab.gather"):
+            vals = blocked_edge_values(bg, flat_edge_vals)
+            ident = jnp.asarray(REDUCE_IDENTITY[reduce], vals.dtype)
+            mask = bg.edge_mask
+            while mask.ndim < vals.ndim:
+                mask = mask[..., None]
+            vals = jnp.where(mask, vals, ident)
+        out = reduce_partials(bg, _block_partials(bg, vals, reduce), reduce)
+    return _slab_epilogue(out, reduce, epilogue)
 
 
 def tocab_gather_src(bg: BlockedGraph, values: jnp.ndarray) -> jnp.ndarray:

@@ -39,28 +39,6 @@ INF_DEPTH = jnp.iinfo(jnp.int32).max // 2
 DEFAULT_ALPHA = 15.0
 
 
-def _resolve_traversal(obj, schedule: str, alpha, workload: str,
-                       impl: str = "slab"):
-    """Concretize ``schedule="auto"`` / ``impl="auto"`` / ``alpha=None``
-    from the tuning DB.
-
-    Runs outside jit (the public wrappers call it before dispatching to the
-    jitted bodies) so the jit cache is keyed on the concrete values and a
-    re-tune takes effect on the next call."""
-    want_auto = schedule == "auto"
-    rs = tocab.resolve_schedule(obj, schedule, workload=workload)
-    ri = tocab.resolve_impl(obj, impl, workload=workload)
-    rs, ri = tocab._reconcile_fused(rs, ri, schedule, impl)
-    if alpha is None:
-        if want_auto:
-            from repro.tune.plan import resolve_alpha
-
-            alpha = resolve_alpha(obj, workload=workload)
-        else:
-            alpha = DEFAULT_ALPHA
-    return rs, float(alpha), ri
-
-
 def _callbacks_enabled() -> bool:
     """Per-iteration telemetry uses ``jax.debug.callback`` (a host call per
     loop iteration).  On by default — the CPU-scale graphs don't notice —
@@ -169,10 +147,8 @@ def _frontier_reach(
 
     ``use_pull`` selects TOCAB pull (dense phase) vs the frontier-bounded
     push of :func:`_push_reach` in ``budget`` arc slots (sparse phase).
-    Both are lowered; `lax.cond` picks at runtime.
-    ``schedule``/``impl`` must already be concrete (no ``"auto"`` here —
-    the public wrappers resolve them before tracing).  The branches are
-    the named scopes ``traversal.pull`` and ``traversal.push``."""
+    Both are lowered; `lax.cond` picks at runtime.  The branches are the
+    named scopes ``traversal.pull`` and ``traversal.push``."""
 
     def pull_branch(f):
         with jax.named_scope("traversal.pull"):
@@ -193,28 +169,24 @@ def bfs(
     bg_pull: Optional[BlockedGraph],
     source: jnp.ndarray,
     max_iters: int = 0,
-    alpha: Optional[float] = None,
+    alpha: float = DEFAULT_ALPHA,
     schedule: str = "uniform",
     impl: str = "slab",
 ):
     """Direction-optimizing BFS.  ``dg``/``bg_pull`` are over Gᵀ edges
     oriented (src→dst) = (in-neighbour → vertex), i.e. the pull layout.
 
-    ``schedule="auto"`` / ``impl="auto"`` consult the tuning DB for the
-    pull phase; ``alpha=None`` takes the tuned Beamer α under ``"auto"``
-    and the paper's 15 otherwise.
+    ``schedule`` and ``impl`` are the pull levels' engine, as in
+    :func:`~repro.core.tocab.tocab_pull`; ``alpha`` is Beamer's switch.
 
-    The call is an ``obs`` span ``bfs`` (resolve and dispatch; the search
-    runs on after it returns), and each level of the compiled loop is the
-    named scope ``bfs.level``.
+    The call is an ``obs`` span ``bfs`` (dispatch; the search runs on after
+    it returns), and each level of the compiled loop is the named scope
+    ``bfs.level``.
 
     Returns (depth int32[n], levels int32, push_iters, pull_iters)."""
     with obs.span("bfs"):
-        schedule, alpha, impl = _resolve_traversal(
-            bg_pull if bg_pull is not None else dg, schedule, alpha, "bfs",
-            impl)
-        return _bfs_jit(dg, bg_pull, source, max_iters, alpha, schedule,
-                        impl)
+        return _bfs_jit(dg, bg_pull, source, max_iters, float(alpha),
+                        schedule, impl)
 
 
 @partial(jax.jit, static_argnames=("max_iters", "alpha", "schedule", "impl"))
@@ -262,7 +234,7 @@ def bc(
     bg_pull: Optional[BlockedGraph],
     source: jnp.ndarray,
     max_levels: int = 64,
-    alpha: Optional[float] = None,
+    alpha: float = DEFAULT_ALPHA,
     schedule: str = "uniform",
     impl: str = "slab",
 ):
@@ -273,9 +245,8 @@ def bc(
     :func:`bfs`.
 
     Returns (bc_scores f32[n], depth, sigma)."""
-    schedule, alpha, impl = _resolve_traversal(
-        bg_pull if bg_pull is not None else dg, schedule, alpha, "bfs", impl)
-    return _bc_jit(dg, bg_pull, source, max_levels, alpha, schedule, impl)
+    return _bc_jit(dg, bg_pull, source, max_levels, float(alpha), schedule,
+                   impl)
 
 
 @partial(jax.jit, static_argnames=("max_levels", "alpha", "schedule",
@@ -357,9 +328,6 @@ def sssp(
     """Bellman-Ford SSSP (min-plus semiring), TOCAB pull per iteration.
 
     ``dg`` must carry edge weights.  Returns (dist f32[n], iters)."""
-    schedule, _, impl = _resolve_traversal(
-        bg_pull if bg_pull is not None else dg, schedule, DEFAULT_ALPHA,
-        "bfs", impl)
     return _sssp_jit(dg, bg_pull, source, max_iters, schedule, impl)
 
 
@@ -412,9 +380,6 @@ def connected_components(
 
     ``dg_t`` is the transpose edge set (labels must flow both directions
     for *weak* connectivity).  Returns (labels int32[n], iters)."""
-    schedule, _, impl = _resolve_traversal(
-        bg_pull if bg_pull is not None else dg, schedule, DEFAULT_ALPHA,
-        "bfs", impl)
     return _cc_jit(dg, dg_t, bg_pull, max_iters, schedule, impl)
 
 

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from repro.core.partition import BlockedGraph
 from repro.kernels.common import LANE, roundup
 from repro.obs.metrics import registry as _obs
-from repro.resilience import chaos as _chaos
 
 from .kernel import fused_pull_pallas, fused_push_pallas
 from .ref import fused_edge_reduce_ref, fused_pull_ref, fused_push_ref
@@ -103,7 +102,6 @@ def fused_pull(
 ):
     """out[dst] = ⊕ values[src] (⊗ edge_val), partials never leaving fast
     memory; optional affine epilogue ``out*mul + add`` fused in."""
-    _chaos.maybe_raise("kernel.tocab_fused.op")  # opt-in fault-injection site
     assert bg.direction == "pull"
     _check_epilogue(reduce, epilogue)
     backend = backend or default_backend()
@@ -151,7 +149,6 @@ def fused_push(
     """Push with the ``block_contrib`` gather kept in fast memory.  Blocks
     own disjoint destination windows, so any ``block_order`` (the balance
     module's bin-major one included) is bit-identical."""
-    _chaos.maybe_raise("kernel.tocab_fused.op")  # opt-in fault-injection site
     assert bg.direction == "push"
     _check_epilogue(reduce, epilogue)
     backend = backend or default_backend()
@@ -197,7 +194,6 @@ def fused_edge_reduce(
     """Edge-value → compacted-side aggregate, no partial slab.  The scan
     path serves both backends — messages come from the blocked edge-value
     slab, not a value window, so there is no gather to confine."""
-    _chaos.maybe_raise("kernel.tocab_fused.op")  # opt-in fault-injection site
     _check_epilogue(reduce, epilogue)
     del backend  # single implementation today; kept for API symmetry
     _record_fused(bg, "fused_edge_reduce", flat_edge_vals.shape[1:],

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from repro.core.partition import BlockedGraph
 from repro.core.tocab import reduce_partials
 from repro.kernels.common import LANE, roundup
-from repro.resilience import chaos as _chaos
 
 from .kernel import tocab_spmm_pallas
 from .ref import tocab_spmm_ref
@@ -56,7 +55,6 @@ def tocab_spmm_partials(
     overrides the global partial-slab width — the sparsity-aware scheduler
     passes the dense bin's (much smaller) static row budget, shrinking the
     kernel's one-hot scatter matmul accordingly."""
-    _chaos.maybe_raise("kernel.tocab_spmm.op")  # opt-in fault-injection site
     assert bg.direction == "pull"
     squeeze = x.ndim == 1
     if squeeze:

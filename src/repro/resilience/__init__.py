@@ -1,26 +1,22 @@
-"""Process-wide resilience layer: fault injection, degradation, retries.
+"""Process-wide resilience layer: fault injection and retries on IO paths.
 
-GraphCage's premise is *choosing among engine variants* per workload; a
-production deployment additionally has to survive any one of them failing
-— a Pallas lowering error on a new backend, a corrupt tuning DB, a torn
-checkpoint, a flaky filesystem.  This package turns every such "works on
-my backend" assumption into a tested degradation path:
+A deployment has to survive a torn checkpoint, a corrupt tuning DB or a
+flaky filesystem.  This package turns each such IO assumption into a
+tested recovery path:
 
 * :mod:`repro.resilience.chaos` — deterministic, seed-driven fault
   injection (``REPRO_CHAOS=<seed>:<rate>`` or programmatic
-  :func:`~repro.resilience.chaos.inject`) with named sites in kernel
-  dispatch, tuner trials, tune-DB and checkpoint IO, and the serve batch
-  path.
-* :mod:`repro.resilience.degrade` — the engine degradation ladder
-  (fused → slab → reference) behind ``impl="auto"`` and
-  ``allow_fallback=True``, with per-(graph, engine) verdict memoization
-  and ``resilience.fallbacks`` obs counters.
+  :func:`~repro.resilience.chaos.inject`) with named sites in tuner
+  trials, tune-DB and checkpoint IO, and the serve batch path.
 * :mod:`repro.resilience.retry` — retry/backoff/timeout policies for
   checkpoint IO, tune-DB persistence, tuner trials, and serving.
+
+Engine dispatch is not among them: the caller's ``schedule`` and ``impl``
+are the engine, and a kernel that fails to lower or compile raises.
 
 Everything records into :data:`repro.obs.metrics.registry` under the
 ``resilience.*`` metric names rather than printing ad-hoc warnings.
 """
-from . import chaos, degrade, retry  # noqa: F401
+from . import chaos, retry  # noqa: F401
 from .chaos import ChaosError  # noqa: F401
 from .retry import Policy  # noqa: F401

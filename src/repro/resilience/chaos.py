@@ -14,10 +14,9 @@ Two ways to arm it:
   env configuration.  Tests use this to force a specific failure exactly
   once.
 
-Sites call :func:`maybe_raise` at dispatch time (host Python — safe at
-jit trace time, where a raised fault aborts the trace and is caught by
-the degradation ladder in :mod:`repro.resilience.degrade`).  Every
-injection increments ``resilience.chaos_injected{site}``.
+Sites call :func:`maybe_raise` on host Python paths that recover by
+retrying (:mod:`repro.resilience.retry`) or rebuilding.  Every injection
+increments ``resilience.chaos_injected{site}``.
 """
 from __future__ import annotations
 
@@ -46,12 +45,10 @@ ENV_SPEC = "REPRO_CHAOS"
 ENV_SITES = "REPRO_CHAOS_SITES"
 
 #: sites armed by rate-based injection when ``REPRO_CHAOS_SITES`` is unset.
-#: Every one of them sits on a *recoverable* path (degradation ladder,
-#: retry policy, or quarantine-and-rebuild), so a chaos run of the test
-#: suite exercises fallbacks rather than manufacturing unhandled crashes.
+#: Every one of them sits on a *recoverable* path (retry policy, or
+#: quarantine-and-rebuild), so a chaos run of the test suite exercises
+#: recoveries rather than manufacturing unhandled crashes.
 DEFAULT_SITES = frozenset({
-    "kernel.tocab_fused",   # fused-impl dispatch in repro.core.tocab
-    "kernel.tocab_spmm",    # dense-bin Pallas dispatch in repro.core.balance
     "ckpt.save",            # train/checkpoint.py write path (retried)
     "ckpt.restore",         # train/checkpoint.py read path (retried)
     "tune.db_load",         # tune/db.py load (retried, quarantine on corrupt)
@@ -62,9 +59,6 @@ DEFAULT_SITES = frozenset({
 #: every named site, including the opt-in ones rate-based injection skips
 #: unless ``REPRO_CHAOS_SITES`` names them.
 KNOWN_SITES = tuple(sorted(DEFAULT_SITES | {
-    "kernel.tocab_slab",        # slab rung of the ladder (opt-in)
-    "kernel.tocab_fused.op",    # kernels/tocab_fused ops entry (opt-in)
-    "kernel.tocab_spmm.op",     # kernels/tocab_spmm ops entry (opt-in)
     "tune.trial",               # tuner trial execution (opt-in)
 }))
 
@@ -151,8 +145,7 @@ def enabled() -> bool:
 
 
 def active_for(site: str) -> bool:
-    """True when rate-based injection can fire at ``site`` — tests that
-    assert *which* engine ran (not its results) skip under this."""
+    """True when rate-based injection can fire at ``site``."""
     cfg = _active()
     return cfg is not None and cfg.rate > 0 and site in cfg.sites
 

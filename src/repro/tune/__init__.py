@@ -13,8 +13,9 @@ device kind, dtype) the way XLA/Triton autotune kernels:
   ``repro.obs`` spans, everything recorded);
 * :mod:`repro.tune.db`       — schema-versioned JSON DB under
   ``experiments/tune/`` with an in-process plan cache;
-* :mod:`repro.tune.plan`     — read side: ``schedule="auto"`` resolution
-  for the engines, tuned-layout builders for callers that can rebuild;
+* :mod:`repro.tune.plan`     — read side: the tuned engine arguments a
+  caller passes to an entry (:func:`engine_kwargs`), tuned-layout builders
+  for callers that can rebuild;
 * :mod:`repro.tune.tuner`    — orchestration; ``python -m repro.tune``
   (``tune`` / ``show`` / ``apply``) is the CLI over the benchmark suite.
 """
@@ -30,6 +31,7 @@ from .db import DB_SCHEMA, db_path, default_dir, device_key, entry_key  # noqa: 
 from .plan import (  # noqa: F401
     TunedPlan,
     blocked_for,
+    engine_kwargs,
     resolve_alpha,
     resolve_plan,
     resolve_schedule,

@@ -56,8 +56,8 @@ IO_POLICY = Policy(max_attempts=3, base_delay=0.02,
 DB_SCHEMA = "repro.tune.db/v1"
 DB_FILENAME = "TUNE_DB.json"
 
-# (abspath -> (mtime, db dict)) — the in-process cache; schedule="auto"
-# resolution must not re-read the file per engine call.
+# (abspath -> (mtime, db dict)) — the in-process cache; a tuned call's
+# plan lookup must not re-read the file per engine call.
 _CACHE: dict = {}
 
 

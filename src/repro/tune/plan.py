@@ -1,28 +1,30 @@
 """Plan resolution: map a graph (host, device, or blocked) to its tuned
 configuration.
 
-This is the read side of the tuning DB, and the only part of ``repro.tune``
-the hot engines touch: ``schedule="auto"`` on ``pagerank`` / ``spmv`` /
-``tocab_pull`` / ``tocab_push`` / the traversal kernels calls
-:func:`resolve_schedule`, which consults the in-process plan cache, then
-the persistent DB, then falls back to the hard-coded defaults.  Resolution
-reads only *static* graph metadata (the build-time fingerprint), so it is
-safe at jit trace time.
+This is the read side of the tuning DB.  The engines in ``repro.core``
+take a concrete ``schedule`` and ``impl`` and never read the DB; a caller
+that wants the tuned engine asks here first and passes the answer on::
+
+    pagerank(dg, bg, **engine_kwargs(bg, "pagerank"))
+    bfs(dg, bg, src, alpha=resolve_alpha(bg), **engine_kwargs(bg, "bfs"))
+
+Each resolver consults the in-process plan cache, then the persistent DB,
+then falls back to the engines' own defaults.  Resolution reads only
+*static* graph metadata (the build-time fingerprint).
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional
 
+from repro.core.traversal import DEFAULT_ALPHA
 from repro.obs.metrics import registry as _obs
 
 from . import db
 from .space import WORKLOADS, Candidate
 
 __all__ = ["TunedPlan", "resolve_plan", "resolve_schedule", "resolve_impl",
-           "resolve_alpha", "blocked_for", "clear_cache"]
-
-DEFAULT_ALPHA = 15.0
+           "resolve_alpha", "engine_kwargs", "blocked_for", "clear_cache"]
 
 # (fingerprint, device, dtype, workload) -> Optional[TunedPlan]
 # Negative results are cached too: an untuned run must not stat() the DB
@@ -86,7 +88,7 @@ def resolve_plan(obj, workload: str = "pagerank", dtype: str = "float32",
     memo_key = (fp, device, dtype, workload, path, mtime)
     if memo_key in _PLANS:
         plan = _PLANS[memo_key]
-        _obs.counter("tune.plan_lookups", "schedule=auto resolutions").inc(
+        _obs.counter("tune.plan_lookups", "tuned-plan resolutions").inc(
             result="memory" if plan else "miss", workload=workload)
         return plan
     entries = db.load(path).get("entries", {})
@@ -100,7 +102,7 @@ def resolve_plan(obj, workload: str = "pagerank", dtype: str = "float32",
                 source="db" if wl == workload else f"db:{wl}")
             break
     _PLANS[memo_key] = plan
-    _obs.counter("tune.plan_lookups", "schedule=auto resolutions").inc(
+    _obs.counter("tune.plan_lookups", "tuned-plan resolutions").inc(
         result=plan.source if plan else "miss", workload=workload)
     return plan
 
@@ -108,11 +110,10 @@ def resolve_plan(obj, workload: str = "pagerank", dtype: str = "float32",
 def resolve_schedule(obj, workload: str = "pagerank",
                      dtype: str = "float32",
                      db_dir: Optional[str] = None) -> str:
-    """Concrete ``schedule`` for ``schedule="auto"``: the plan's choice when
-    its engine family is blocked, else ``uniform``.  A plan whose winner is
-    a *flat* engine pins ``uniform`` — the caller already committed to a
-    blocked engine, and the balanced dispatch only pays when tuning said
-    so."""
+    """The tuned ``schedule``: the plan's choice when its engine family is
+    blocked, else ``uniform``.  A plan whose winner is a *flat* engine pins
+    ``uniform`` — the caller already committed to a blocked engine, and the
+    balanced dispatch only pays when tuning said so."""
     plan = resolve_plan(obj, workload=workload, dtype=dtype, db_dir=db_dir)
     if plan is None or not plan.candidate.blocked:
         return "uniform"
@@ -121,9 +122,9 @@ def resolve_schedule(obj, workload: str = "pagerank",
 
 def resolve_impl(obj, workload: str = "pagerank", dtype: str = "float32",
                  db_dir: Optional[str] = None) -> str:
-    """Concrete ``impl`` for ``impl="auto"``: the plan's slab/fused pick for
-    a blocked winner, else ``slab``.  Entries written before the impl axis
-    existed deserialize with the ``slab`` default, so old DBs stay valid."""
+    """The tuned ``impl``: the plan's slab/fused pick for a blocked winner,
+    else ``slab``.  Entries written before the impl axis existed
+    deserialize with the ``slab`` default, so old DBs stay valid."""
     plan = resolve_plan(obj, workload=workload, dtype=dtype, db_dir=db_dir)
     if plan is None or not plan.candidate.blocked:
         return "slab"
@@ -136,6 +137,15 @@ def resolve_alpha(obj, workload: str = "bfs", dtype: str = "float32",
     """Tuned Beamer α for traversal, falling back to the paper's 15."""
     plan = resolve_plan(obj, workload=workload, dtype=dtype, db_dir=db_dir)
     return default if plan is None else plan.alpha
+
+
+def engine_kwargs(obj, workload: str, dtype: str = "float32") -> dict:
+    """The tuned engine of ``obj`` for ``workload``, as the keyword
+    arguments every engine entry takes: ``{"schedule": …, "impl": …}``.
+    The tuner never pairs ``fused`` with ``balanced``, so the pair is
+    always one the engines accept."""
+    return {"schedule": resolve_schedule(obj, workload=workload, dtype=dtype),
+            "impl": resolve_impl(obj, workload=workload, dtype=dtype)}
 
 
 def blocked_for(g, workload: str = "pagerank", dtype: str = "float32",

@@ -11,7 +11,6 @@ from repro.core import (
 from repro.core.balance import (
     BIN_DENSE, BIN_NAMES, BIN_SPARSE, bin_pull_partials, require_schedule,
 )
-from repro.resilience import degrade
 
 INF = float("inf")
 
@@ -186,10 +185,6 @@ def test_bin_partials_shape(setup):
         assert sub.shape == (k, rb)
 
 
-@pytest.mark.skipif(
-    degrade.fallback_allowed("slab", None),
-    reason="REPRO_RESILIENCE_FALLBACK degrades the missing-schedule error "
-           "to the reference rung instead of raising")
 def test_missing_schedule_raises(setup):
     g, dg, _, _ = setup
     bg = build_blocked(g, block_size=128, classify=False)

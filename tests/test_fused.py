@@ -19,15 +19,6 @@ from repro.core import (
     tocab_push,
 )
 from repro.core.traversal import bfs, sssp
-from repro.resilience import chaos
-
-# Engine-identity tests (HLO shapes, fused obs counters) assert *which*
-# engine ran; under chaos-smoke the ladder may legitimately degrade fused
-# dispatch, so they skip when that site is armed.
-_chaos_on_fused = pytest.mark.skipif(
-    chaos.active_for("kernel.tocab_fused"),
-    reason="chaos can degrade fused dispatch to slab — engine-identity "
-           "assertions don't hold under fault injection")
 
 
 @pytest.fixture(scope="module")
@@ -186,24 +177,33 @@ def test_fused_push_bin_major_order(setup):
 
 
 # --------------------------------------------------------------------- #
-# dispatch / reconciliation
+# dispatch
 # --------------------------------------------------------------------- #
 def test_fused_balanced_conflict(setup):
     g, _, bg, _ = setup
-    x = _vals(g.n)
     with pytest.raises(ValueError, match="balanced"):
-        tocab_pull(bg, x, schedule="balanced", impl="fused")
-    # the auto side yields instead of raising
-    np.testing.assert_allclose(
-        np.asarray(tocab_pull(bg, x, schedule="balanced", impl="auto")),
-        np.asarray(tocab_pull(bg, x, schedule="balanced")),
-        rtol=1e-6, atol=1e-7)
+        tocab_pull(bg, _vals(g.n), schedule="balanced", impl="fused")
 
 
-def test_fused_unknown_impl(setup):
-    g, _, bg, _ = setup
+_ENTRIES = {
+    "tocab_pull": lambda g, dg, bg, bgp, impl: tocab_pull(
+        bg, _vals(g.n), impl=impl),
+    "tocab_push": lambda g, dg, bg, bgp, impl: tocab_push(
+        bgp, _vals(g.n), impl=impl),
+    "tocab_edge_reduce": lambda g, dg, bg, bgp, impl: tocab_edge_reduce(
+        bg, _vals(g.m), impl=impl),
+    "pagerank": lambda g, dg, bg, bgp, impl: pagerank(dg, bg, impl=impl),
+    "spmv": lambda g, dg, bg, bgp, impl: spmv(dg, bg, _vals(g.n), impl=impl),
+}
+
+
+@pytest.mark.parametrize("impl", ["warp", "auto"])
+@pytest.mark.parametrize("entry", sorted(_ENTRIES))
+def test_fused_unknown_impl(setup, entry, impl):
+    """The caller's ``impl`` is the engine: a name that is not one, the
+    retired ``"auto"`` included, raises at the entry."""
     with pytest.raises(ValueError, match="impl"):
-        tocab_pull(bg, _vals(g.n), impl="warp")
+        _ENTRIES[entry](*setup, impl)
 
 
 # --------------------------------------------------------------------- #
@@ -261,7 +261,6 @@ def test_traversal_fused(setup):
 # --------------------------------------------------------------------- #
 # the point of the exercise: no partial slab in HBM
 # --------------------------------------------------------------------- #
-@_chaos_on_fused
 def test_fused_lowering_has_no_partial_slab(setup):
     """The compiled fused program must not allocate the
     ``(num_blocks, local_budget)`` partial buffer the slab path round-trips
@@ -286,7 +285,6 @@ def test_fused_lowering_has_no_partial_slab(setup):
         assert s not in fused_hlo, f"fused lowering materializes {s}"
 
 
-@_chaos_on_fused
 def test_fused_obs_counters(setup):
     from repro.obs.metrics import registry as _obs
 

@@ -287,7 +287,7 @@ def _compiled(entry: str) -> str:
     if entry == "pagerank":
         fn, args = _pagerank_jit, (
             DeviceGraph.from_host(g), build_blocked(g, block_size=16),
-            "gc-pull", 0.85, 1e-4, 20, True, "uniform", "slab", False)
+            "gc-pull", 0.85, 1e-4, 20, True, "uniform", "slab")
     elif entry == "bfs":
         fn, args = _bfs_jit, (
             DeviceGraph.from_host(g), build_blocked(g, block_size=16),

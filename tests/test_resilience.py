@@ -1,25 +1,28 @@
-"""Resilience layer: chaos injection, the degradation ladder, retry/timeout
-policies, hardened checkpoint/tune-DB IO, and graph structural validation.
+"""Resilience layer: chaos injection, retry/timeout policies, hardened
+checkpoint/tune-DB IO, graph structural validation, and engine dispatch
+without fallbacks.
 
-The invariant under test throughout: faults change *where the work runs*
-(ladder rung, retry attempt, rebuilt DB), never *what comes out* — the
-fallback result must be bit-identical to the engine it lands on, and IO
-recovery must never destroy good data.
+The invariant under test throughout: faults change *how often the work
+runs* (retry attempt, rebuilt DB), never *what comes out* — IO recovery
+must never destroy good data.  Engine dispatch has no recovery path: the
+caller's ``impl`` runs, and its failure reaches the caller.
 """
+import ast
 import json
 import os
+import pathlib
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import (
-    DeviceGraph, build_blocked, from_edges, graph_fingerprint, pagerank,
-    rmat_graph, tocab_pull,
+    build_blocked, from_edges, graph_fingerprint, rmat_graph,
+    tocab_edge_reduce, tocab_pull, tocab_push,
 )
 from repro.core.graph import Graph, GraphValidationError, validate_graph
 from repro.obs.metrics import registry as _obs
-from repro.resilience import chaos, degrade
+from repro.resilience import chaos
 from repro.resilience.chaos import ChaosError
 from repro.resilience.retry import Policy, call_with_timeout, retry
 from repro.train import checkpoint as ckpt
@@ -32,15 +35,12 @@ from repro.tune.space import Candidate, SearchSpace, TrialBudget
 @pytest.fixture(autouse=True)
 def clean_resilience(monkeypatch):
     """Each test starts with chaos disarmed (even under the chaos-smoke CI
-    env — these tests inject their own faults) and no memoized verdicts."""
+    env — these tests inject their own faults)."""
     monkeypatch.delenv(chaos.ENV_SPEC, raising=False)
     monkeypatch.delenv(chaos.ENV_SITES, raising=False)
-    monkeypatch.delenv(degrade.ENV_FALLBACK, raising=False)
     chaos.reset()
-    degrade.clear()
     yield
     chaos.reset()
-    degrade.clear()
 
 
 def small_graph(seed=0, scale=7):
@@ -82,7 +82,7 @@ def test_chaos_spec_and_env_parsing(monkeypatch):
     chaos.reset()  # force env re-read
     assert chaos.enabled()
     assert chaos.active_for("a") and chaos.active_for("b")
-    assert not chaos.active_for("kernel.tocab_fused")
+    assert not chaos.active_for("ckpt.save")
 
     monkeypatch.setenv(chaos.ENV_SPEC, "nonsense")
     chaos.reset()
@@ -107,11 +107,12 @@ def test_chaos_inject_queue():
 
 def test_opt_in_sites_not_default():
     """Rate-based injection at the sites that have no recovery path must be
-    opt-in, or a chaos run manufactures unhandled crashes."""
-    for site in ("kernel.tocab_slab", "tune.trial",
-                 "kernel.tocab_fused.op", "kernel.tocab_spmm.op"):
-        assert site not in chaos.DEFAULT_SITES
-        assert site in chaos.KNOWN_SITES
+    opt-in, or a chaos run manufactures unhandled crashes.  Kernel dispatch
+    has no recovery path at all, so it has no site."""
+    assert "tune.trial" not in chaos.DEFAULT_SITES
+    assert "tune.trial" in chaos.KNOWN_SITES
+    assert set(chaos.DEFAULT_SITES) <= set(chaos.KNOWN_SITES)
+    assert not [s for s in chaos.KNOWN_SITES if s.startswith("kernel.")]
 
 
 # --------------------------- retry / timeout --------------------------- #
@@ -187,92 +188,77 @@ def test_deterministic_jitter():
     assert pol.delay("s", 1) != pol.delay("s", 2)
 
 
-# -------------------------- degradation ladder -------------------------- #
+# --------------------------- engine dispatch --------------------------- #
 
-def test_fallback_allowed_semantics(monkeypatch):
-    assert degrade.fallback_allowed("fused", True) is True
-    assert degrade.fallback_allowed("auto", False) is False
-    assert degrade.fallback_allowed("auto", None) is True
-    assert degrade.fallback_allowed("fused", None) is False
-    monkeypatch.setenv(degrade.ENV_FALLBACK, "1")
-    assert degrade.fallback_allowed("fused", None) is True
-
-
-def test_fused_fallback_bit_identical_and_memoized():
-    g = small_graph(seed=11)
-    bg = build_blocked(g, block_size=32)
-    x = jnp.asarray(np.random.default_rng(0).random(g.n, dtype=np.float32))
-    want = np.asarray(tocab_pull(bg, x, impl="slab"))
-
-    before = _obs.counter("resilience.fallbacks").value(
-        site="tocab_pull", error="ChaosError",
-        **{"from": "fused", "to": "slab"}) or 0
-    chaos.inject("kernel.tocab_fused")
-    got = np.asarray(tocab_pull(bg, x, impl="fused", allow_fallback=True))
-    np.testing.assert_array_equal(got, want)
-    assert _obs.counter("resilience.fallbacks").value(
-        site="tocab_pull", error="ChaosError",
-        **{"from": "fused", "to": "slab"}) == before + 1
-
-    # the verdict is memoized for this (graph, site): later auto/fused
-    # dispatches start at slab instead of re-failing
-    assert degrade.apply_verdict(bg.fingerprint, "tocab_pull",
-                                 "fused") == "slab"
+_ENTRIES = {
+    "tocab_pull": ("fused_pull", "pull",
+                   lambda bg, g: tocab_pull(bg, jnp.ones((g.n,)), impl="fused")),
+    "tocab_push": ("fused_push", "push",
+                   lambda bg, g: tocab_push(bg, jnp.ones((g.n,)), impl="fused")),
+    "tocab_edge_reduce": ("fused_edge_reduce", "pull",
+                          lambda bg, g: tocab_edge_reduce(
+                              bg, jnp.ones((g.m,)), impl="fused")),
+}
 
 
-def test_ladder_reaches_reference():
-    g = small_graph(seed=12)
-    bg = build_blocked(g, block_size=32)
-    x = jnp.asarray(np.random.default_rng(1).random(g.n, dtype=np.float32))
-    want = np.asarray(tocab_pull(bg, x, impl="slab"))
+@pytest.mark.parametrize("entry", sorted(_ENTRIES))
+def test_explicit_impl_failure_propagates(monkeypatch, entry):
+    """A failing kernel reaches the caller as it was raised: no other engine
+    runs in its place and nothing is recorded as a fallback."""
+    import repro.kernels.tocab_fused as fused_pkg
 
-    eng = _obs.counter("tocab.engine_traces")
-    r0 = eng.value(engine="tocab_pull_reference", direction="pull")
-    chaos.inject("kernel.tocab_fused")
-    chaos.inject("kernel.tocab_slab")
-    got = np.asarray(tocab_pull(bg, x, impl="fused", allow_fallback=True))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)
-    assert eng.value(engine="tocab_pull_reference", direction="pull") > r0
-
-
-def test_no_fallback_without_opt_in():
+    kernel, direction, call = _ENTRIES[entry]
     g = small_graph(seed=13)
-    bg = build_blocked(g, block_size=32)
-    x = jnp.ones((g.n,), jnp.float32)
-    chaos.inject("kernel.tocab_fused")
-    with pytest.raises(ChaosError):
-        tocab_pull(bg, x, impl="fused", allow_fallback=False)
+    bg = build_blocked(g, block_size=32, direction=direction)
+
+    class KernelBroke(RuntimeError):
+        pass
+
+    err = KernelBroke("does not lower")
+
+    def boom(*args, **kwargs):
+        raise err
+
+    monkeypatch.setattr(fused_pkg, kernel, boom)
+    with pytest.raises(KernelBroke) as info:
+        call(bg, g)
+    assert info.value is err
+    fallbacks = _obs.snapshot().get("resilience.fallbacks")
+    assert not (fallbacks and fallbacks["series"])
 
 
-def test_pagerank_auto_fallback_acceptance(tmp_path, monkeypatch):
-    """ISSUE acceptance: with chaos forcing fused kernel dispatch to fail,
-    ``pagerank(..., impl="auto")`` (resolved to fused by the tuning DB)
-    completes, bit-identical to ``impl="slab"``, and the obs snapshot
-    records the fallback."""
-    monkeypatch.setenv("REPRO_TUNE_DIR", str(tmp_path))
-    tune_plan.clear_cache()
-    g = small_graph(seed=14, scale=8)
-    dg = DeviceGraph.from_host(g)
-    bg = build_blocked(g, block_size=64)
-    # a tuned entry whose winner is the fused tocab engine → auto = fused
-    cand = Candidate(engine="tocab", direction="pull", schedule="uniform",
-                     impl="fused", block_size=64)
-    key = tune_db.entry_key(graph_fingerprint(g), workload="pagerank")
-    tune_db.put_entry(key, {"chosen": cand.to_json(), "workload": "pagerank"})
+def _imported_modules(path: pathlib.Path, package: str):
+    """Absolute names of every module ``path`` imports, and of every name a
+    ``from`` import takes (relative imports resolved against ``package``)."""
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            yield from (a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            parts = [node.module] if node.module else []
+            if node.level:
+                base = package.split(".")
+                parts = base[:len(base) - node.level + 1] + parts
+            module = ".".join(parts)
+            yield module
+            yield from (f"{module}.{a.name}" for a in node.names)
 
-    want, it_want = pagerank(dg, bg, impl="slab", max_iters=30)
 
-    chaos.configure(seed=5, rate=1.0, sites={"kernel.tocab_fused"})
-    got, it_got = pagerank(dg, bg, impl="auto", max_iters=30)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(it_got) == int(it_want)
-
-    snap = _obs.snapshot()
-    assert "resilience.fallbacks" in snap
-    series = snap["resilience.fallbacks"]["series"]
-    assert any(s["labels"].get("site") == "tocab_pull" and
-               s["labels"].get("from") == "fused" for s in series)
-    tune_plan.clear_cache()
+def test_core_imports_neither_tune_nor_resilience():
+    """Imports run tune → core → kernels only: the engines take their
+    schedule and impl from the caller, never from the tuning DB, and have
+    no fallback or fault-injection hooks."""
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    files = sorted((src / "repro" / "core").glob("*.py")) + sorted(
+        (src / "repro" / "kernels").rglob("*.py"))
+    assert len(files) > 10
+    bad = []
+    for f in files:
+        rel = f.relative_to(src).with_suffix("")
+        for mod in _imported_modules(f, ".".join(rel.parts[:-1])):
+            if mod.split(".")[:2] in (["repro", "tune"],
+                                      ["repro", "resilience"]):
+                bad.append(f"{rel}: {mod}")
+    assert not bad, bad
 
 
 # ------------------------------- tuner -------------------------------- #

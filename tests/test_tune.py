@@ -1,9 +1,9 @@
 """repro.tune: fingerprints, DB round-trips, analytic prune, end-to-end
-tuning, and the engines' ``schedule="auto"`` read path.
+tuning, and the tuned call ``**engine_kwargs(bg, workload)``.
 
 The tuner is allowed to change *where time goes*, never *what comes out*:
-``schedule="auto"`` must be bit-for-bit the engine's output under the
-resolved schedule, and numerically the flat baseline's answer.
+a tuned call must be bit-for-bit the engine's output under the resolved
+schedule, and numerically the flat baseline's answer.
 """
 import os
 import json
@@ -18,7 +18,7 @@ from repro.core import (
 )
 from repro.tune import (
     BUDGETS, Candidate, SearchSpace, Trial, default_candidate, device_key,
-    entry_key, resolve_plan, resolve_schedule, tune,
+    engine_kwargs, entry_key, resolve_plan, resolve_schedule, tune,
 )
 from repro.tune import analytic, db as tune_db, plan as tune_plan, runner
 from repro.tune.space import WORKLOADS
@@ -192,17 +192,18 @@ def test_auto_matches_baseline(tune_dir, make_graph):
     _force_plan(g, Candidate(engine="tocab", schedule="balanced",
                              block_size=128))
     assert resolve_schedule(bg) == "balanced"
-    rank_auto, it_auto = pagerank(dg, bg, variant="gc-pull", schedule="auto")
+    rank_auto, it_auto = pagerank(dg, bg, variant="gc-pull",
+                                  **engine_kwargs(bg, "pagerank"))
     rank_res, it_res = pagerank(dg, bg, variant="gc-pull",
                                 schedule="balanced")
-    # bit-for-bit: auto IS the resolved schedule, not a reimplementation
+    # bit-for-bit: the tuned call IS the resolved schedule
     assert (np.asarray(rank_auto) == np.asarray(rank_res)).all()
     assert int(it_auto) == int(it_res)
     rank_base, _ = pagerank(dg, None, variant="base")
     np.testing.assert_allclose(rank_auto, rank_base, atol=1e-7)
 
     x = jnp.asarray(np.random.default_rng(0).random(g.n, dtype=np.float32))
-    np.testing.assert_allclose(spmv(dg, bg, x, schedule="auto"),
+    np.testing.assert_allclose(spmv(dg, bg, x, **engine_kwargs(bg, "spmv")),
                                baseline_pull(dg, x), rtol=2e-5, atol=2e-5)
 
 
@@ -212,7 +213,8 @@ def test_auto_without_db_is_uniform(tune_dir):
     assert resolve_plan(bg) is None
     assert resolve_schedule(bg) == "uniform"
     x = jnp.ones((g.n,), jnp.float32)
-    out = tocab_pull(bg, x, schedule="auto")
+    assert engine_kwargs(bg, "spmv") == {"schedule": "uniform", "impl": "slab"}
+    out = tocab_pull(bg, x, **engine_kwargs(bg, "spmv"))
     np.testing.assert_array_equal(out, tocab_pull(bg, x, schedule="uniform"))
 
 

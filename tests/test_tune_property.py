@@ -1,5 +1,5 @@
-"""Hypothesis property: ``schedule="auto"`` ≡ the flat baseline, whatever
-the tuning DB pins.
+"""Hypothesis property: a tuned call (``**engine_kwargs``) ≡ the flat
+baseline, whatever the tuning DB pins.
 
 The plan-resolution layer sits between every engine call and the persisted
 DB — for any graph and any persisted winner, it must stay a pure dispatch
@@ -20,7 +20,7 @@ from repro.core import (
     DeviceGraph, baseline_pull, build_blocked, from_edges, graph_fingerprint,
     tocab_pull,
 )
-from repro.tune import Candidate, entry_key
+from repro.tune import Candidate, engine_kwargs, entry_key
 from repro.tune import db as tune_db, plan as tune_plan
 
 
@@ -58,7 +58,7 @@ def test_auto_equals_baseline(tmp_path_factory, g, forced):
         tune_plan.clear_cache()
         dg = DeviceGraph.from_host(g)
         x = jnp.asarray(np.linspace(0.0, 1.0, g.n, dtype=np.float32))
-        out = tocab_pull(bg, x, schedule="auto")
+        out = tocab_pull(bg, x, **engine_kwargs(bg, "pagerank"))
         np.testing.assert_allclose(out, baseline_pull(dg, x),
                                    rtol=2e-5, atol=2e-5)
     finally:
