@@ -25,6 +25,7 @@ __all__ = [
     "from_edges",
     "validate_graph",
     "graph_fingerprint",
+    "is_symmetric",
     "rmat_graph",
     "uniform_random_graph",
     "grid_graph",
@@ -203,6 +204,11 @@ class DeviceGraph:
     # structural fingerprint (tuning-db key); static → usable at trace time
     fingerprint: Optional[str] = dataclasses.field(
         default=None, metadata=dict(static=True))
+    # every arc (u, v) has as many reverse arcs (v, u), so row v of the CSR
+    # lists v's in-neighbours (see :func:`is_symmetric`); static, so the
+    # traversals choose their pull at trace time.  Hand-built graphs: False.
+    symmetric: bool = dataclasses.field(
+        default=False, metadata=dict(static=True))
 
     @property
     def m(self) -> int:
@@ -211,7 +217,8 @@ class DeviceGraph:
     @classmethod
     def from_host(cls, g: Graph) -> "DeviceGraph":
         """Copy ``g`` to the device; the copies are the ``obs`` span
-        ``device_graph.place``."""
+        ``device_graph.place``, then the host's exact test of
+        :func:`is_symmetric` the span ``device_graph.symmetric``."""
         src, dst = g.edges()
         out_degree, in_degree = g.out_degree, g.in_degree
         with obs.span("device_graph.place"):
@@ -223,7 +230,42 @@ class DeviceGraph:
                 in_degree=jnp.asarray(in_degree, jnp.int32),
                 vals=None if g.vals is None else jnp.asarray(g.vals, jnp.float32),
             )
-        return cls(n=g.n, fingerprint=graph_fingerprint(g), **arrays)
+        with obs.span("device_graph.symmetric"):
+            symmetric = is_symmetric(g.n, src, dst, out_degree, in_degree)
+        return cls(n=g.n, fingerprint=graph_fingerprint(g),
+                   symmetric=symmetric, **arrays)
+
+
+def is_symmetric(n: int, src: np.ndarray, dst: np.ndarray,
+                 out_degree: np.ndarray, in_degree: np.ndarray) -> bool:
+    """Whether every arc (u, v) of the arc list ``src``/``dst`` over ``n``
+    vertices has as many reverse arcs (v, u), exactly.
+
+    Unequal in- and out-degrees answer False at once.  Otherwise one sort
+    of the arcs that are not self-loops, keyed by their unordered pair and
+    then their direction, puts each pair's forward arcs just before its
+    backward ones: the graph is symmetric iff the forward arcs and the
+    backward arcs, each read in that order, list the same pairs.  Where no
+    pair repeats an arc, that is each even position holding a forward arc
+    followed by its reverse, checked first."""
+    if not np.array_equal(out_degree, in_degree):
+        return False
+    loop = src == dst
+    if loop.any():
+        src, dst = src[~loop], dst[~loop]
+    # (lo·n + hi)·2 + direction < 2·n² < 2^63 for n < 2^31
+    key = np.minimum(src, dst).astype(np.int64)
+    key *= n
+    key += np.maximum(src, dst)
+    key <<= 1
+    key += src > dst
+    key.sort()
+    # each even position a forward arc, followed by its reverse
+    if np.array_equal(key[0::2], key[1::2] ^ 1):
+        return True
+    backward = (key & 1).astype(bool)
+    key >>= 1
+    return bool(np.array_equal(key[~backward], key[backward]))
 
 
 def from_edges(

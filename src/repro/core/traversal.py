@@ -12,6 +12,10 @@ iterations go through TOCAB (the working set only exceeds fast memory when
 the frontier is large).  The hybrid switch uses the classic α heuristic on
 the frontier's out-edge count, which also bounds a push level's work: a
 push level expands only the frontier's CSR rows, into ⌊m/α⌋ arc slots.
+On a symmetric graph (``DeviceGraph.symmetric``) a pull level needs no
+TOCAB: each CSR row lists its vertex's in-neighbours, so the level counts
+the frontier vertices in every row with one gather per arc and a prefix
+sum.
 """
 from __future__ import annotations
 
@@ -134,6 +138,33 @@ def _push_reach(dg: DeviceGraph, frontier: jnp.ndarray, budget: int):
     return jnp.zeros((dg.n,), jnp.float32).at[nbr].set(1.0, mode="drop")
 
 
+def _row_count_reach(dg: DeviceGraph, frontier: jnp.ndarray):
+    """reached[v] = 1 where row v of the CSR holds a frontier vertex, else
+    0: the pull of a level on a symmetric graph, whose row v lists v's
+    in-neighbours.  One gather per arc marks the arcs that end on the
+    frontier, an int32 prefix sum counts them (a float32 one is inexact
+    past 2^24 arcs), and row v's count is the prefix at its end less the
+    prefix at its start: no scatter and no padded slot."""
+    if dg.m == 0:
+        return jnp.zeros((dg.n,), jnp.float32)
+    get = dict(mode="promise_in_bounds", wrap_negative_indices=False)
+    hit = frontier.astype(jnp.int32).at[dg.dst].get(**get)
+    pre = _scan(hit, jax.lax.cumsum, jnp.add)
+    # pre[rowptr[v] - 1] counts the hits before row v (0 for an empty prefix)
+    last = jnp.maximum(dg.rowptr - 1, 0)
+    before = jnp.where(dg.rowptr > 0,
+                       pre.at[last].get(indices_are_sorted=True, **get), 0)
+    return (before[1:] > before[:-1]).astype(jnp.float32)
+
+
+def _record_pull_engine(algo: str, engine: str):
+    """Trace-time telemetry, as ``tocab.engine_traces``: one count each
+    time a traversal's pull branch is traced, by the engine it lowers."""
+    _obs.counter(
+        "traversal.pull_engine_traces", "pull-branch traces by engine"
+    ).inc(engine=engine, algo=algo)
+
+
 def _frontier_reach(
     dg: DeviceGraph,
     bg_pull: Optional[BlockedGraph],
@@ -142,18 +173,26 @@ def _frontier_reach(
     budget: int,
     schedule: str = "uniform",
     impl: str = "slab",
+    algo: str = "bfs",
 ):
     """reached[dst] > 0 iff an arc (src, dst) has frontier[src] > 0.
 
-    ``use_pull`` selects TOCAB pull (dense phase) vs the frontier-bounded
+    ``use_pull`` selects the pull (dense phase) vs the frontier-bounded
     push of :func:`_push_reach` in ``budget`` arc slots (sparse phase).
     Both are lowered; `lax.cond` picks at runtime.  The branches are the
-    named scopes ``traversal.pull`` and ``traversal.push``."""
+    named scopes ``traversal.pull`` and ``traversal.push``.  The pull is
+    :func:`_row_count_reach` on a symmetric graph, else TOCAB's max-pull
+    (the flat ``baseline_pull`` without a blocked layout)."""
 
     def pull_branch(f):
         with jax.named_scope("traversal.pull"):
+            if dg.symmetric:
+                _record_pull_engine(algo, "rows")
+                return _row_count_reach(dg, f)
             if bg_pull is None:
+                _record_pull_engine(algo, "baseline")
                 return tocab.baseline_pull(dg, f, reduce="max")
+            _record_pull_engine(algo, impl)
             return tocab.tocab_pull(bg_pull, f, reduce="max",
                                     schedule=schedule, impl=impl)
 
@@ -178,6 +217,8 @@ def bfs(
 
     ``schedule`` and ``impl`` are the pull levels' engine, as in
     :func:`~repro.core.tocab.tocab_pull`; ``alpha`` is Beamer's switch.
+    On a symmetric ``dg`` the pull levels count rows instead
+    (:func:`_row_count_reach`) and read neither ``bg_pull`` nor them.
 
     The call is an ``obs`` span ``bfs`` (dispatch; the search runs on after
     it returns), and each level of the compiled loop is the named scope
@@ -275,7 +316,7 @@ def _bc_jit(
         m_frontier, use_pull, budget = _beamer_switch(dg, frontier, alpha)
         _emit_frontier("bc", frontier, m_frontier, use_pull, budget)
         reached = _frontier_reach(dg, bg_pull, frontier, use_pull, budget,
-                                  schedule, impl)
+                                  schedule, impl, algo="bc")
         new_frontier = (reached > 0) & (depth >= INF_DEPTH)
         depth = jnp.where(new_frontier, level + 1, depth)
         # σ[dst] += Σ σ[src] over tree edges (src on frontier level).

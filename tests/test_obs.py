@@ -257,8 +257,12 @@ def test_device_graph_place_span():
     g = G.from_edges(32, rng.integers(0, 32, 100), rng.integers(0, 32, 100))
     dg = DeviceGraph.from_host(g)
     assert dg.m == g.m
-    [ev] = trace.events()
-    assert ev["name"] == "device_graph.place" and ev["parent"] is None
+    # the placement, then the host's symmetry test: two spans, neither
+    # inside the other, so `device_graph.place` times the copies alone
+    place, symmetric = trace.events()
+    assert place["name"] == "device_graph.place" and place["parent"] is None
+    assert symmetric["name"] == "device_graph.symmetric"
+    assert symmetric["parent"] is None
 
 
 # -------------------- named scopes in compiled HLO -------------------- #
